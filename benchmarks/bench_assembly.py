@@ -137,13 +137,9 @@ def run_stage_graph(cases, bs: int = 32, reps: int = 5) -> list[tuple]:
     K_ii factorization plus streaming K_bb instead of the full permuted
     K): ~1.3x on the (2,2)x(20,20) 2D case, nil on small-interior 3D
     boxes."""
-    import numpy as np
-
     from repro.fem.decomposition import decompose_elasticity_problem
-    from repro.fem.regularization import fixing_dofs_regularization
     from repro.feti import FetiConfig
-    from repro.feti.assembly import make_cluster_preprocessor
-    from repro.feti.dirichlet import own_boundary_masks
+    from repro.feti.assembly import host_stacks, make_cluster_preprocessor
 
     rows = []
     for dim, grid, eps in cases:
@@ -157,19 +153,8 @@ def run_stage_graph(cases, bs: int = 32, reps: int = 5) -> list[tuple]:
             fc = FetiConfig(schur=cfg, preconditioner="dirichlet",
                             share_factor=share)
             static, prep = make_cluster_preprocessor(prob, fc)
-            np_ = static["node_perm"]
-            split = static["split"]
-            Kp = np.stack([
-                fixing_dofs_regularization(sd.K, sd.fixing_dofs)[np_][:, np_]
-                for sd in prob.subdomains])
-            Btp = np.stack([sd.Bt[np_] for sd in prob.subdomains])
-            dperm = split.dperm
-            Kd = np.stack([sd.K for sd in prob.subdomains]
-                          )[:, dperm][:, :, dperm]
-            if static["share"]:
-                Kd = Kd[:, split.n_i:, split.n_i:]
-            args = [jnp.asarray(Kp), jnp.asarray(Btp), jnp.asarray(Kd),
-                    jnp.asarray(own_boundary_masks(prob, split))]
+            st = host_stacks(prob, static, fc)
+            args = [jnp.asarray(st[k]) for k in ("Kp", "Btp", "Kd", "Zb")]
 
             def both_stages(*a):
                 _, F, Sb = prep(*a)

@@ -81,8 +81,7 @@ def run(cases=(("heat", 2, (2, 2), (8, 8)), ("heat", 2, (2, 2), (16, 16)),
 
         import numpy as np
 
-        from repro.feti.assembly import make_cluster_preprocessor
-        from repro.fem.regularization import fixing_dofs_regularization
+        from repro.feti.assembly import host_stacks, make_cluster_preprocessor
 
         def preprocess_time(cfg, explicit, dirichlet=False,
                             share_factor="auto"):
@@ -94,26 +93,10 @@ def run(cases=(("heat", 2, (2, 2), (8, 8)), ("heat", 2, (2, 2), (16, 16)),
                 preconditioner="dirichlet" if dirichlet else "lumped",
                 share_factor=share_factor)
             static, prep = make_cluster_preprocessor(prob, fc)
-            np_ = static["node_perm"]
-            Kp = np.stack([
-                fixing_dofs_regularization(sd.K, sd.fixing_dofs)[np_][:, np_]
-                for sd in prob.subdomains
-            ])
-            Btp = np.stack([sd.Bt[np_] for sd in prob.subdomains])
-            args = [jnp.asarray(Kp), jnp.asarray(Btp)]
+            st = host_stacks(prob, static, fc)
+            args = [jnp.asarray(st["Kp"]), jnp.asarray(st["Btp"])]
             if dirichlet:
-                from repro.feti.dirichlet import own_boundary_masks
-
-                split = static["split"]
-                dperm = split.dperm
-                Kd = np.stack([sd.K for sd in prob.subdomains]
-                              )[:, dperm][:, :, dperm]
-                if static["share"]:
-                    # shared interior factor: the stage streams only K_bb
-                    # (K_ib comes off the dual stage's permuted K input)
-                    Kd = Kd[:, split.n_i:, split.n_i:]
-                args += [jnp.asarray(Kd),
-                         jnp.asarray(own_boundary_masks(prob, split))]
+                args += [jnp.asarray(st["Kd"]), jnp.asarray(st["Zb"])]
             idx = 2 if dirichlet else (1 if explicit else 0)
             us = time_fn(lambda *a: prep(*a)[idx], *args, reps=reps)
             st = preprocess_cluster(prob, fc)

@@ -70,7 +70,9 @@ def run(dim: int = 2, sub_grid=(4, 4), elems_per_sub=(16, 16),
         # preprocessing: re-run the compiled factorize+assemble the state
         # carries on already-placed stacks (multi-step regime, fixed pattern)
         L_d = st.L.unpack() if isinstance(st.L, PackedBlocks) else st.L
-        Kp = L_d @ jnp.swapaxes(L_d, -1, -2)  # any SPD stack, placed right
+        # any SPD stack, packed as prep takes it and placed right
+        Kp = st.index.pack(L_d @ jnp.swapaxes(L_d, -1, -2),
+                           diag_identity_pad=True)
         t_pre = time_fn(lambda a, b: st.prep(a, b)[1], Kp, st.Btp, reps=reps)
 
         lam = jax.device_put(jnp.zeros((nl,)), shlib.replicated_sharding(mesh))

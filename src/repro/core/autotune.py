@@ -54,6 +54,8 @@ __all__ = [
     "assembly_bytes",
     "pattern_fingerprint",
     "default_block_sizes",
+    "offered_on",
+    "pallas_vmem_bytes",
     "plan_cache_dir",
     "clear_plan_cache",
 ]
@@ -71,7 +73,10 @@ __all__ = [
 #     peak FLOPs in DeviceModel, itemsize-scaled byte model, dtype-cast
 #     measurement probes) — an f32 plan must never be served to an f64
 #     stage or vice versa.
-SPACE_VERSION = 5
+# v6: device models are keyed by device_kind (which joined the key), and
+#     on TPU Pallas candidates are offered only for f32/bf16 stages whose
+#     kernels fit VMEM (offered_on).
+SPACE_VERSION = 6
 
 # Pallas kernels only run natively on TPU; elsewhere they fall back to
 # interpret mode, which is orders of magnitude slower. The model multiplies
@@ -254,6 +259,53 @@ def assembly_cost(meta: SteppedMeta, cfg: SchurAssemblyConfig,
             "flops": fl["total"], "bytes": by["total"], "ops": by["ops"]}
 
 
+def pallas_vmem_bytes(meta: SteppedMeta, cfg: SchurAssemblyConfig,
+                      dtype: str = "f32") -> int:
+    """Modeled VMEM working set of the Pallas kernels ``cfg`` runs
+    (:func:`repro.kernels.common.vmem_bytes`): the fused kernel, or the
+    larger of the TRSM and SYRK kernels."""
+    from repro.kernels.common import vmem_bytes
+
+    bs, bm = meta.block_size, meta.rhs_block_size
+    n_pad = meta.num_row_blocks * bs
+    m_pad = meta.num_col_blocks * bm
+    db = itemsize(compute_dtype(dtype))
+    packed = cfg.storage == "packed"
+
+    def need(kernel):
+        return vmem_bytes(kernel, n_pad, m_pad, bs, bm, db)
+
+    if cfg.fused:
+        return need("fused_packed" if packed else "fused")
+    out = 0
+    if cfg.trsm_variant != "dense":
+        out = need("trsm_packed" if packed else "trsm")
+    if cfg.syrk_variant != "dense":
+        out = max(out, need("syrk"))
+    return out
+
+
+def offered_on(device: DeviceModel, cfg: SchurAssemblyConfig,
+               meta: SteppedMeta, block_mask: Optional[np.ndarray],
+               dtype: str) -> bool:
+    """Whether the planner may offer ``cfg`` on ``device``. On a TPU a
+    Pallas candidate needs an f32/bf16 stage (Mosaic has no f64), factor
+    and RHS blocks that are multiples of the 128-lane vreg width (Mosaic
+    refuses narrower block shapes and the dense kernels' unaligned lane
+    slices), and a kernel working set within the VMEM limit the kernels ask
+    for; off-TPU every candidate is enumerated (Pallas ones run interpreted
+    and carry the interpret penalty)."""
+    if not (cfg.use_pallas and device.kind == "tpu"):
+        return True
+    if dtype not in ("f32", "bf16"):
+        return False
+    if meta.block_size % 128 or meta.rhs_block_size % 128:
+        return False
+    from repro.kernels.common import VMEM_LIMIT_BYTES
+
+    return pallas_vmem_bytes(meta, cfg, dtype) <= VMEM_LIMIT_BYTES
+
+
 # --------------------------------------------------------------------------
 # candidate enumeration
 # --------------------------------------------------------------------------
@@ -401,7 +453,7 @@ def _cache_key(fingerprint: str, device: DeviceModel,
     # `dtype` keys the precision axis (the per-dtype FLOP peaks and the
     # itemsize-scaled byte model rank candidates differently).
     h = hashlib.sha256()
-    h.update(f"v{SPACE_VERSION}:{device.kind}:{stage}:{fingerprint}:"
+    h.update(f"v{SPACE_VERSION}:{device.name}:{stage}:{fingerprint}:"
              f"{int(measured)}:{storage or 'any'}:{dtype}:".encode())
     h.update(",".join(str(b) for b in sorted(block_sizes)).encode())
     return h.hexdigest()
@@ -608,6 +660,8 @@ def plan_from_builder(
             if bk not in built:
                 built[bk] = meta_builder(*bk)
             meta, mask = built[bk]
+            if not offered_on(device, cfg, meta, mask, dtype):
+                continue
             cost = assembly_cost(meta, cfg, device, block_mask=mask,
                                  dtype=dtype)
             scored.append((cost["total_s"], cfg, meta, mask))
@@ -716,7 +770,7 @@ def plan_from_builder(
         baseline_measured_s=baseline_meas,
         device=device.kind,
         key=key,
-        candidates=len(candidates),
+        candidates=len(scored),
         dtype=dtype,
     )
     if cache:

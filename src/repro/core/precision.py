@@ -25,6 +25,8 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 
 __all__ = [
@@ -37,20 +39,16 @@ __all__ = [
     "itemsize",
     "tol_floor",
     "default_refine_steps",
+    "HIGHEST",
+    "einsum",
+    "mm",
 ]
 
 # name <-> dtype tables. bf16 comes from ml_dtypes (a jax dependency), so
 # this module stays importable without initializing a jax backend.
-try:  # pragma: no cover - ml_dtypes ships with every supported jax
-    import ml_dtypes
-
-    _BF16 = np.dtype(ml_dtypes.bfloat16)
-except ImportError:  # pragma: no cover
-    _BF16 = None
-
-_NAME_TO_DTYPE = {"f64": np.dtype(np.float64), "f32": np.dtype(np.float32)}
-if _BF16 is not None:
-    _NAME_TO_DTYPE["bf16"] = _BF16
+_BF16 = np.dtype(ml_dtypes.bfloat16)
+_NAME_TO_DTYPE = {"f64": np.dtype(np.float64), "f32": np.dtype(np.float32),
+                  "bf16": _BF16}
 _DTYPE_TO_NAME = {v: k for k, v in _NAME_TO_DTYPE.items()}
 
 SUPPORTED_DTYPES = tuple(_NAME_TO_DTYPE)
@@ -84,7 +82,7 @@ def compute_dtype(dtype: Any) -> np.dtype:
     library Cholesky anywhere, and every Pallas kernel here already
     accumulates sub-f32 inputs in f32 — "bf16-accumulate-f32")."""
     dt = canonical_dtype(dtype)
-    if _BF16 is not None and dt == _BF16:
+    if dt == _BF16:
         return np.dtype(np.float32)
     return dt
 
@@ -101,15 +99,8 @@ def solve_dtype(dtype: Any, refine_steps: int) -> np.dtype:
 
 def eps(dtype: Any) -> float:
     """Machine epsilon of a (canonicalized) dtype as a python float."""
-    dt = canonical_dtype(dtype)
-    try:
-        return float(np.finfo(dt).eps)
-    except ValueError:
-        # older numpy releases don't route np.finfo through ml_dtypes'
-        # registration for bfloat16 — ml_dtypes.finfo always works
-        import ml_dtypes
-
-        return float(ml_dtypes.finfo(dt).eps)
+    # ml_dtypes.finfo covers bfloat16, which np.finfo does not know
+    return float(ml_dtypes.finfo(canonical_dtype(dtype)).eps)
 
 
 def itemsize(dtype: Any) -> int:
@@ -134,3 +125,72 @@ def default_refine_steps(dtype: Any) -> int:
     subdomain operators this pipeline factorizes (docs/mixed_precision.md
     derives the kappa * eps bound)."""
     return 0 if canonical_dtype(dtype) == np.dtype(np.float64) else 2
+
+
+# Matmul precision of every product over the stored stacks (factorization,
+# assembly, operators, preconditioners, the Pallas kernels). On a TPU an
+# f32 contraction at the default precision runs as a single bf16 MXU pass
+# (~3 significant digits); the f32 path's refinement and its 1e-8 oracle
+# contract assume true f32 products. XLA:CPU computes at full precision
+# whatever is asked, so CPU results do not change.
+HIGHEST = "highest"
+
+# An f64 contraction on a TPU (no f64 units) is emulated; for matrix-vector
+# shapes XLA:TPU's emulated dot holds temporaries several times its
+# operands (7.4 GB for the 1.2 GB f64 packed-K matvec of feti-heat-2d's
+# refinement, against 16 GB of HBM). An elementwise product and a sum need
+# no more than the product itself, so contractions whose full index space is
+# at most this many times their largest operand take that route there.
+_ELEMENTWISE_SPAN = 2
+
+
+def _f64_on_tpu(*xs) -> bool:
+    import jax
+
+    return (jnp.result_type(*xs) == np.float64
+            and jax.default_backend() == "tpu")
+
+
+def _elementwise_einsum(subscripts: str, a, b):
+    """``einsum`` of two operands as one broadcast product and a sum (no
+    repeated indices within an operand), or None where the product's index
+    space exceeds :data:`_ELEMENTWISE_SPAN` times the largest operand."""
+    ins, out = subscripts.replace(" ", "").split("->")
+    sa, sb = ins.split(",")
+    union = list(dict.fromkeys(sa + sb))
+    size = dict(zip(sa, a.shape)) | dict(zip(sb, b.shape))
+    if int(np.prod([size[c] for c in union])) > _ELEMENTWISE_SPAN * max(
+            int(np.prod(a.shape)), int(np.prod(b.shape))):
+        return None
+
+    def expand(x, sub):
+        order = [c for c in union if c in sub]
+        x = jnp.transpose(x, [sub.index(c) for c in order])
+        return x.reshape([size[c] if c in sub else 1 for c in union])
+
+    r = jnp.sum(expand(a, sa) * expand(b, sb),
+                axis=tuple(i for i, c in enumerate(union) if c not in out))
+    kept = [c for c in union if c in out]
+    return jnp.transpose(r, [kept.index(c) for c in out])
+
+
+def einsum(subscripts: str, a, b):
+    """Two-operand ``jnp.einsum`` at :data:`HIGHEST` precision; on a TPU,
+    f64 contractions of matrix-vector shape run elementwise (see
+    :data:`_ELEMENTWISE_SPAN`)."""
+    if _f64_on_tpu(a, b):
+        r = _elementwise_einsum(subscripts, a, b)
+        if r is not None:
+            return r
+    return jnp.einsum(subscripts, a, b, precision=HIGHEST)
+
+
+def mm(a, b):
+    """``a @ b`` at :data:`HIGHEST` precision (f64 matrix-vector products
+    on a TPU elementwise, as in :func:`einsum`)."""
+    if _f64_on_tpu(a, b) and a.ndim == 2 and b.ndim in (1, 2):
+        r = _elementwise_einsum("ij,j->i" if b.ndim == 1 else "ij,jk->ik",
+                                a, b)
+        if r is not None:
+            return r
+    return jnp.matmul(a, b, precision=HIGHEST)

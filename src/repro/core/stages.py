@@ -207,7 +207,7 @@ class StageGraph:
 
     def joint_key(self, device: DeviceModel, measured: bool) -> str:
         h = hashlib.sha256()
-        h.update(f"v{SPACE_VERSION}:graph:{device.kind}:"
+        h.update(f"v{SPACE_VERSION}:graph:{device.name}:"
                  f"{int(measured)}:".encode())
         for s in self.stages:
             bss = ",".join(str(b) for b in sorted(s.candidate_block_sizes()))

@@ -27,6 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import mm
 from repro.core.stepped import SteppedMeta
 
 __all__ = ["syrk_dense", "syrk_input_split", "syrk_output_split"]
@@ -34,7 +35,7 @@ __all__ = ["syrk_dense", "syrk_input_split", "syrk_output_split"]
 
 def syrk_dense(Y: jax.Array) -> jax.Array:
     """Baseline: full dense SYRK F = YᵀY."""
-    return Y.T @ Y
+    return mm(Y.T, Y)
 
 
 def syrk_input_split(Y: jax.Array, meta: SteppedMeta) -> jax.Array:
@@ -48,7 +49,7 @@ def syrk_input_split(Y: jax.Array, meta: SteppedMeta) -> jax.Array:
         if w == 0:
             continue
         Yk = Y[r0:r1, :w]
-        F = F.at[:w, :w].add(Yk.T @ Yk)
+        F = F.at[:w, :w].add(mm(Yk.T, Yk))
     return F
 
 
@@ -70,9 +71,9 @@ def syrk_output_split(Y: jax.Array, meta: SteppedMeta) -> jax.Array:
         if s >= meta.n:  # structurally zero columns -> zero row/col of F
             continue
         Ci = Y[s:, i0:i1]
-        F = F.at[i0:i1, i0:i1].set(Ci.T @ Ci)
+        F = F.at[i0:i1, i0:i1].set(mm(Ci.T, Ci))
         if i0 > 0:
-            strip = Ci.T @ Y[s:, :i0]
+            strip = mm(Ci.T, Y[s:, :i0])
             F = F.at[i0:i1, :i0].set(strip)
             F = F.at[:i0, i0:i1].set(strip.T)
     return F

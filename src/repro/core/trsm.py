@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import mm
 from repro.core.stepped import SteppedMeta
 
 __all__ = [
@@ -106,14 +107,14 @@ def trsm_factor_split(
         if r1 >= n:
             continue
         if block_mask is None:
-            Y = Y.at[r1:, :w].add(-(L[r1:, r0:r1] @ Yk))
+            Y = Y.at[r1:, :w].add(-mm(L[r1:, r0:r1], Yk))
         else:
             # Pruning: touch only structurally nonzero subdiagonal blocks.
             for i in range(k + 1, nb):
                 if not block_mask[i, k]:
                     continue
                 i0, i1 = meta.row_block(i)
-                Y = Y.at[i0:i1, :w].add(-(L[i0:i1, r0:r1] @ Yk))
+                Y = Y.at[i0:i1, :w].add(-mm(L[i0:i1, r0:r1], Yk))
     return Y
 
 
@@ -156,5 +157,5 @@ def trsm_factor_split_packed(L, B: jax.Array, meta: SteppedMeta) -> jax.Array:
             continue
         for i, s in index.col_slots(k):
             i0, i1 = meta.row_block(i)
-            Y = Y.at[i0:i1, :w].add(-(vals[s][: i1 - i0, :b] @ Yk))
+            Y = Y.at[i0:i1, :w].add(-mm(vals[s][: i1 - i0, :b], Yk))
     return Y

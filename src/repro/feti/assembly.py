@@ -50,10 +50,9 @@ from repro.core.stages import GraphPlan, StageGraph, StageSpec
 from repro.core.stepped import SteppedMeta
 from repro.fem.decomposition import FetiProblem
 from repro.fem.meshgen import structured_mesh
-from repro.fem.regularization import fixing_dofs_regularization
 from repro.feti import dirichlet as dirlib
 from repro.feti import sharded as shlib
-from repro.feti.config import FetiConfig, _coerce_config
+from repro.feti.config import FetiConfig, _coerce_config, as_feti_config
 from repro.obs.trace import annotation, current_tracer
 from repro.sparse import (
     block_pattern,
@@ -69,7 +68,8 @@ from repro.sparse.packed import (
 )
 
 __all__ = ["ClusterState", "preprocess_cluster", "batched_assemble",
-           "expand_node_perm", "expand_node_pattern"]
+           "expand_node_perm", "expand_node_pattern", "host_stacks",
+           "bt_pattern"]
 
 
 def expand_node_perm(node_perm: np.ndarray, ndpn: int) -> np.ndarray:
@@ -141,7 +141,8 @@ class ClusterState:
     n_real: Optional[int] = None  # subdomain count before mesh padding
     relabeled: bool = False  # multiplier columns in stepped (relabeled) order
     # the compiled preprocessor, for the multi-step regime: new values,
-    # same pattern, zero recompiles. Signature depends on the stage set:
+    # same pattern, zero recompiles. Kp is the packed (S, n_blocks, bs, bs)
+    # regularized K stack (host_stacks). Signature depends on the stage set:
     #   (Kp, Btp) -> (L, F)                          dual only
     #   (Kp, Btp, Kd, Zb) -> (L, F, Sb)              + dirichlet
     #   (Kp, Btp, Kbb, Zb) -> (L, F, Sb)             + dirichlet, shared
@@ -258,6 +259,16 @@ def batched_assemble(
     return jax.vmap(one)(L, Btp, col_perm, inv_col_perm)
 
 
+def bt_pattern(sd) -> np.ndarray:
+    """(n, m_max) nonzero pattern of a subdomain's B̃ᵀ, from its compact
+    (b_rows, m) gluing record — also for pattern-only decompositions
+    (``decompose_problem(..., assemble_values=False)``), whose dense Bt is
+    a placeholder."""
+    P = np.zeros((sd.n, len(sd.b_rows)), dtype=bool)
+    P[sd.b_rows[: sd.m], np.arange(sd.m)] = True
+    return P
+
+
 def _share_valid(problem: FetiProblem,
                  split: dirlib.BoundaryInteriorSplit) -> bool:
     """The interior-factor dedup is valid iff every subdomain's fixing
@@ -269,6 +280,41 @@ def _share_valid(problem: FetiProblem,
     bset[split.boundary] = True
     return all(bool(bset[sd.fixing_dofs].all())
                for sd in problem.subdomains)
+
+
+def subdomain_chunk(fc: FetiConfig, S: int) -> int:
+    """Subdomains one step of the compiled prep processes together: all of
+    them, except for f64 stacks on a TPU. XLA:TPU has no f64 units; it
+    emulates f64 products with temporaries that grow with the batch (the
+    16-subdomain f64 feti-elasticity-2d prep with the Dirichlet stage asks
+    for 25 GB of a v5e's 16 GB), so there the prep walks the subdomains one
+    at a time."""
+    if fc.dtype_name == "f64" and jax.default_backend() == "tpu":
+        return 1
+    return S
+
+
+def _map_chunks(prep: Callable, chunk: int) -> Callable:
+    """Run a subdomain-batched ``prep`` over ``chunk``-sized slices of its
+    stacks in sequence (``lax.map``); outputs are re-stacked."""
+
+    def run(*stacks):
+        S = jax.tree_util.tree_leaves(stacks)[0].shape[0]
+        if S % chunk:
+            raise ValueError(f"{S} subdomains do not split into chunks of "
+                             f"{chunk}")
+
+        def split(x):
+            return x.reshape((S // chunk, chunk) + x.shape[1:])
+
+        def join(x):
+            return x.reshape((S,) + x.shape[2:])
+
+        out = jax.lax.map(lambda xs: prep(*xs),
+                          jax.tree_util.tree_map(split, stacks))
+        return jax.tree_util.tree_map(join, out)
+
+    return run
 
 
 def make_cluster_preprocessor(problem: FetiProblem, config=None,
@@ -347,7 +393,7 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
     # fill-reducing order otherwise
     node_perm = split.dperm if share else fill_perm
     kpat = kpat0[node_perm][:, node_perm]
-    patterns = [sd.Bt[node_perm] != 0 for sd in subs]
+    patterns = [bt_pattern(sd)[node_perm] for sd in subs]
 
     # builders used both by the joint planner (scoring candidate block
     # sizes) and below to materialize the symbolic products for the final
@@ -440,13 +486,15 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
     packed = cfg.storage == "packed"
 
     def _factorize(Kp_l):
-        """Batched numerical factorization in the configured storage."""
+        """Batched numerical factorization in the configured storage.
+        ``Kp_l`` is the packed (S, n_blocks, bs, bs) regularized K stack;
+        dense storage unpacks it transiently inside the program."""
         with jax.named_scope("factorize"):
             if packed:
-                return jax.vmap(
-                    lambda A: block_cholesky_packed(A, index))(Kp_l)
-            return jax.vmap(
-                lambda A: block_cholesky(A, cfg.block_size, mask=block_mask)
+                return jax.vmap(lambda v: block_cholesky_packed(
+                    PackedBlocks(v, index), index))(Kp_l)
+            return jax.vmap(lambda v: block_cholesky(
+                index.unpack_symmetric(v), cfg.block_size, mask=block_mask)
             )(Kp_l)
 
     ni = split.n_i if split is not None else 0
@@ -462,13 +510,14 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
         """The boundary-Schur node of the graph, shared by the local and
         shard_map preps. ``dir_l`` is (Kbb, Zb) when the interior factor
         is shared — K_ib is the dual factor input's off-diagonal slice,
-        unperturbed by the boundary-diagonal regularization — and
-        (Kd, Zb) otherwise."""
+        unperturbed by the boundary-diagonal regularization (read as the
+        transpose of the stored lower slice K_bi) — and (Kd, Zb)
+        otherwise."""
         with jax.named_scope("stage:dirichlet"):
             if share:
                 Kbb_l, Zb_l = dir_l
-                Sb = jax.vmap(d_assemble)(
-                    _interior_factor(L), Kp_l[:, :ni, ni:], Kbb_l)
+                Kib = jnp.swapaxes(index.unpack(Kp_l)[:, ni:, :ni], -1, -2)
+                Sb = jax.vmap(d_assemble)(_interior_factor(L), Kib, Kbb_l)
             else:
                 Kd_l, Zb_l = dir_l
                 Sb = jax.vmap(d_assemble)(Kd_l)
@@ -476,25 +525,22 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
 
     if mesh is None:
 
-        if dirichlet:
-
-            def prep(Kp_stack, Btp_stack, *dir_stacks):
-                L = _factorize(Kp_stack)
+        def prep_cols(Kp_stack, Btp_stack, cp_l, icp_l, *dir_stacks):
+            L = _factorize(Kp_stack)
+            F = None
+            if explicit:
                 with jax.named_scope("stage:dual"):
-                    F = (batched_assemble(L, Btp_stack, cp, icp, env, cfg,
-                                          block_mask) if explicit else None)
+                    F = batched_assemble(L, Btp_stack, cp_l, icp_l, env,
+                                         cfg, block_mask)
+            if dirichlet:
                 return L, F, _dirichlet_stage(L, Kp_stack, *dir_stacks)
+            return L, F
 
-        else:
+        chunk = subdomain_chunk(fc, S)
+        run = prep_cols if chunk >= S else _map_chunks(prep_cols, chunk)
 
-            def prep(Kp_stack, Btp_stack):
-                L = _factorize(Kp_stack)
-                if not explicit:
-                    return L, None
-                with jax.named_scope("stage:dual"):
-                    F = batched_assemble(L, Btp_stack, cp, icp, env, cfg,
-                                         block_mask)
-                return L, F
+        def prep(Kp_stack, Btp_stack, *dir_stacks):
+            return run(Kp_stack, Btp_stack, cp, icp, *dir_stacks)
 
     else:
         from jax.sharding import PartitionSpec as P
@@ -554,6 +600,81 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
     return static, jax.jit(prep)
 
 
+def _pad_packed_identity(vals: np.ndarray, S_pad: int,
+                         index: PackedBlockIndex) -> np.ndarray:
+    """Pad a packed (S, n_blocks, bs, bs) stack with identity matrices (the
+    inert dummy subdomains of a mesh-padded cluster)."""
+    pad = np.zeros((S_pad - vals.shape[0],) + vals.shape[1:], vals.dtype)
+    pad[:, index.diag_slots] = np.eye(index.bs, dtype=vals.dtype)
+    return np.concatenate([vals, pad], axis=0)
+
+
+def host_stacks(problem: FetiProblem, static: dict, config=None) -> dict:
+    """The numeric inputs of the compiled ``prep``, plus the packed K stacks
+    the solution phase keeps, built on the host ONE subdomain at a time.
+
+    Every matrix is regularized, permuted and packed per subdomain in
+    numpy, so no dense (S, n, n) stack exists beyond the problem's own
+    list of subdomain matrices, and nothing dense reaches a device.
+    ``static`` is the first value :func:`make_cluster_preprocessor`
+    returns. Keys, all numpy, at the storage dtype unless noted:
+
+      * ``Kp``: (S, n_blocks, bs, bs) packed regularized K in factor row
+        order, the diagonal tail identity-padded (factorizable);
+      * ``K``: the same layout of the unregularized K (lumped
+        preconditioner);
+      * ``Kreg``: ``Kp`` at f64 with a zero tail, the matrix refinement
+        closes its loop against — only when refinement is on, else None;
+      * ``Btp``: (S, n, m_max) row-permuted B̃ᵀ;
+      * with the Dirichlet stage: ``Kd`` (the (S, n_b, n_b) unregularized
+        K_bb when the interior factor is shared, else the dperm-ordered
+        K), ``Btb`` and ``Zb``.
+    """
+    fc = as_feti_config(config)
+    dtype = fc.dtype_np
+    subs = problem.subdomains
+    S = len(subs)
+    node_perm = static["node_perm"]
+    index: PackedBlockIndex = static["index"]
+    split, share = static["split"], static["share"]
+    shape = (S, index.n_blocks, index.bs, index.bs)
+    Kp = np.empty(shape, dtype)
+    K = np.empty(shape, dtype)
+    Kreg = np.empty(shape, np.float64) if fc.resolved_refine() > 0 else None
+    Kd = []
+    inv_perm = np.argsort(node_perm)
+    for i, sd in enumerate(subs):
+        Ki = index.pack_host(sd.K, np.float64, perm=node_perm)
+        K[i] = Ki
+        # fixing_dofs_regularization's shift ρ = mean(diag K) on the fixing
+        # DOFs' diagonal, at their packed positions (factor row order)
+        q = inv_perm[sd.fixing_dofs]
+        slot = index.diag_slots[q // index.bs]
+        Ki[slot, q % index.bs, q % index.bs] += float(np.mean(np.diag(sd.K)))
+        Kp[i] = Ki
+        if Kreg is not None:
+            Kreg[i] = Ki
+        if fc.dirichlet:
+            # the dirichlet stage eliminates against the UNREGULARIZED K:
+            # K_ii is SPD outright (boundary nonempty pins the kernel) and
+            # the fixing-DOF diagonal shift would perturb S_b on boundary
+            # entries. Shared interior factor: only K_bb is streamed — K_ii
+            # and K_ib already enter through the dual stage's K, whose
+            # interior rows the regularization cannot touch.
+            rows = split.boundary if share else split.dperm
+            Kd.append(sd.K[rows][:, rows])
+    # identity on the padded diagonal tail keeps Kp factorizable
+    tail = np.arange(index.n - (index.nb - 1) * index.bs, index.bs)
+    Kp[:, index.diag_slots[-1], tail, tail] = 1.0
+    out = dict(Kp=Kp, K=K, Kreg=Kreg,
+               Btp=np.stack([sd.Bt[node_perm] for sd in subs]))
+    if fc.dirichlet:
+        out.update(Kd=np.stack(Kd),
+                   Btb=np.stack([sd.Bt[split.boundary] for sd in subs]),
+                   Zb=dirlib.own_boundary_masks(problem, split))
+    return out
+
+
 def preprocess_cluster(problem: FetiProblem, config=None,
                        **deprecated) -> ClusterState:
     """Paper §2.2 'preprocessing': factorize every K_i and (if explicit)
@@ -608,31 +729,10 @@ def preprocess_cluster(problem: FetiProblem, config=None,
     split = static["split"]
     share = static["share"]
 
-    Kreg = np.stack(
-        [fixing_dofs_regularization(sd.K, sd.fixing_dofs) for sd in subs]
-    )
-    Kp = Kreg[:, node_perm][:, :, node_perm]
-    Btp = np.stack([sd.Bt[node_perm] for sd in subs])
-    K_stack = np.stack([sd.K for sd in subs])  # unregularized, shared below
-    Kd = Btb = Zb = None
-    if dirichlet:
-        # the dirichlet stage eliminates against the UNREGULARIZED K:
-        # K_ii is SPD outright (boundary nonempty pins the kernel) and the
-        # fixing-DOF diagonal shift would perturb S_b on boundary entries
-        Btb = np.stack([sd.Bt[split.boundary] for sd in subs])
-        Zb = dirlib.own_boundary_masks(problem, split)
-        if share:
-            # shared interior factor: only K_bb is streamed — K_ii and
-            # K_ib already enter through the dual stage's (regularized) K,
-            # whose interior rows the regularization cannot touch
-            bnd = split.boundary
-            Kd = K_stack[:, bnd][:, :, bnd]
-        else:
-            dperm = split.dperm
-            Kd = K_stack[:, dperm][:, :, dperm]
-    # the lumped preconditioner's K: unregularized, permuted like the
-    # factor so it shares Btp — packed host-side into the fill-mask layout
-    K_perm = K_stack[:, node_perm][:, :, node_perm]
+    stacks = host_stacks(problem, static, fc)
+    Kp, Btp, K_vals = stacks["Kp"], stacks["Btp"], stacks["K"]
+    Kreg_vals = stacks["Kreg"]
+    Kd, Btb, Zb = stacks.get("Kd"), stacks.get("Btb"), stacks.get("Zb")
     f = np.stack([sd.f for sd in subs])
     lam = np.stack([sd.lambda_ids for sd in subs])
 
@@ -651,9 +751,11 @@ def preprocess_cluster(problem: FetiProblem, config=None,
         Btp = shlib.relabel_columns(Btp, cp_np)
         lam = shlib.relabel_columns(lam, cp_np)
         S_pad = shlib.padded_count(S, mesh)
-        Kp = shlib.pad_stack(Kp, S_pad, identity=True)
+        Kp = _pad_packed_identity(Kp, S_pad, index)
+        if Kreg_vals is not None:
+            Kreg_vals = _pad_packed_identity(Kreg_vals, S_pad, index)
         Btp = shlib.pad_stack(Btp, S_pad)
-        K_perm = shlib.pad_stack(K_perm, S_pad)
+        K_vals = shlib.pad_stack(K_vals, S_pad)
         f = shlib.pad_stack(f, S_pad)
         if dirichlet:
             # dummy subdomains: identity K (factorizable interior, S_b = I)
@@ -694,20 +796,13 @@ def preprocess_cluster(problem: FetiProblem, config=None,
                 L, F = prep(Kp_j, Btp_j)
                 sp.sync(L, F)
 
-    # pack K host-side (numpy blocks), then place/shard only the values
+    # the packed K stacks were built host-side (host_stacks): place/shard
+    # only their values
     with tr.span("pack") as sp_pack:
-        K_vals = np.asarray(index.pack(jnp.asarray(K_perm, dtype=dtype)))
         K_packed = PackedBlocks(to_dev(K_vals), index)
-
-        # refinement closes its loop against the f64 regularized permuted
-        # K — the exact matrix whose reduced-precision factor the prep
-        # holds. Packed (fill-mask layout, like the lumped K), kept only
-        # when refinement is on; the f64 pipeline carries nothing extra.
         refine_steps = fc.resolved_refine()
         Kreg_packed = None
-        if refine_steps > 0:
-            Kreg_vals = np.asarray(
-                index.pack(jnp.asarray(Kp, dtype=np.float64)))
+        if Kreg_vals is not None:
             Kreg_packed = PackedBlocks(to_dev(Kreg_vals, dt=np.float64),
                                        index)
 

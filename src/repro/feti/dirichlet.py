@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import mm
 from repro.core import SchurAssemblyConfig, build_stepped_meta, make_assembler
 from repro.core.stepped import SteppedMeta, column_pivots
 from repro.fem.decomposition import FetiProblem
@@ -271,7 +272,7 @@ def restrict_own_boundary(Sb: jax.Array, z: jax.Array) -> jax.Array:
     C = jnp.linalg.cholesky(E)
     ZS = z[:, None] * Sb
     Y = jax.scipy.linalg.cho_solve((C, True), ZS)
-    return Sb - ZS.T @ Y
+    return Sb - mm(ZS.T, Y)
 
 
 def make_dirichlet_assembler(

@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import einsum
 from repro.sparse.packed import (
     PackedBlocks,
     packed_symm_matvec,
@@ -93,7 +94,7 @@ def explicit_dual_apply(F: jax.Array, lambda_ids: jax.Array, n_lambda: int,
                         lam: jax.Array) -> jax.Array:
     """q = Σᵢ B̃ᵢᵀ-scatter( F̃ᵢ · gather(λ) )   (paper eq. 12)."""
     return local_dual_apply(
-        lambda p: jnp.einsum("sab,sb->sa", F, p), lambda_ids, n_lambda, lam)
+        lambda p: einsum("sab,sb->sa", F, p), lambda_ids, n_lambda, lam)
 
 
 def _tri_solve(L, b, transpose):
@@ -122,16 +123,16 @@ def apply_stiffness(K, v: jax.Array) -> jax.Array:
     (packed = the symmetric lower block triangle in fill-mask layout)."""
     if isinstance(K, PackedBlocks):
         return jax.vmap(packed_symm_matvec)(K, v)
-    return jnp.einsum("snk,sk->sn", K, v)
+    return einsum("snk,sk->sn", K, v)
 
 
 def implicit_dual_apply(L, Btp: jax.Array, lambda_ids: jax.Array,
                         n_lambda: int, lam: jax.Array) -> jax.Array:
     """q = Σᵢ scatter( B̃ᵢ L⁻ᵀL⁻¹ B̃ᵢᵀ gather(λ) )  (paper eq. 11)."""
     p_loc = gather_local(lam, lambda_ids)
-    v = jnp.einsum("snm,sm->sn", Btp, p_loc)
+    v = einsum("snm,sm->sn", Btp, p_loc)
     t = solve_with_factor(L, v)
-    q_loc = jnp.einsum("snm,sn->sm", Btp, t)
+    q_loc = einsum("snm,sn->sm", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda)
 
 
@@ -151,9 +152,9 @@ def lumped_preconditioner(K, Bt: jax.Array, lambda_ids: jax.Array,
     """
 
     def apply_local(p):
-        v = jnp.einsum("snm,sm->sn", Bt, p)
+        v = einsum("snm,sm->sn", Bt, p)
         v = apply_stiffness(K, v)
-        return jnp.einsum("snm,sn->sm", Bt, v)
+        return einsum("snm,sn->sm", Bt, v)
 
     return local_dual_apply(apply_local, lambda_ids, n_lambda, w)
 
@@ -173,9 +174,9 @@ def dirichlet_preconditioner(Sb: jax.Array, Btb: jax.Array,
     """
 
     def apply_local(p):
-        v = jnp.einsum("sbm,sm->sb", Btb, p)
-        v = jnp.einsum("sab,sb->sa", Sb, v)
-        return jnp.einsum("sbm,sb->sm", Btb, v)
+        v = einsum("sbm,sm->sb", Btb, p)
+        v = einsum("sab,sb->sa", Sb, v)
+        return einsum("sbm,sb->sm", Btb, v)
 
     return local_dual_apply(apply_local, lambda_ids, n_lambda, w)
 
@@ -184,7 +185,7 @@ def dual_rhs(L, Btp: jax.Array, fp: jax.Array,
              lambda_ids: jax.Array, n_lambda: int, c: jax.Array) -> jax.Array:
     """d = B K⁺ f − c (paper §2.1)."""
     t = solve_with_factor(L, fp)
-    q_loc = jnp.einsum("snm,sn->sm", Btp, t)
+    q_loc = einsum("snm,sn->sm", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda) - c
 
 
@@ -227,9 +228,9 @@ def implicit_dual_apply_refined(L, Kreg, Btp: jax.Array,
     through a reduced-precision factor. ``Btp`` holds exact ±1/0 entries,
     so its (promoting) einsums against the f64 vectors are exact."""
     p_loc = gather_local(lam, lambda_ids)
-    v = jnp.einsum("snm,sm->sn", Btp, p_loc)
+    v = einsum("snm,sm->sn", Btp, p_loc)
     t = solve_with_factor_refined(L, Kreg, v, steps)
-    q_loc = jnp.einsum("snm,sn->sm", Btp, t)
+    q_loc = einsum("snm,sn->sm", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda)
 
 
@@ -238,7 +239,7 @@ def dual_rhs_refined(L, Kreg, Btp: jax.Array, fp: jax.Array,
                      steps: int, c: jax.Array) -> jax.Array:
     """d = B K⁺ f − c with the refined (f64-accurate) interior solve."""
     t = solve_with_factor_refined(L, Kreg, fp, steps)
-    q_loc = jnp.einsum("snm,sn->sm", Btp, t)
+    q_loc = einsum("snm,sn->sm", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda) - c
 
 
@@ -271,7 +272,7 @@ def explicit_dual_apply_many(F: jax.Array, lambda_ids: jax.Array,
                              n_lambda: int, Lam: jax.Array) -> jax.Array:
     """Eq. 12 on a column stack: one (m×m)·(m×r) GEMM per subdomain."""
     return local_dual_apply_many(
-        lambda p: jnp.einsum("sab,sbr->sar", F, p), lambda_ids, n_lambda, Lam)
+        lambda p: einsum("sab,sbr->sar", F, p), lambda_ids, n_lambda, Lam)
 
 
 def solve_with_factor_many(L, B: jax.Array) -> jax.Array:
@@ -300,16 +301,16 @@ def apply_stiffness_many(K, V: jax.Array) -> jax.Array:
     if isinstance(K, PackedBlocks):
         cols = jax.vmap(packed_symm_matvec, in_axes=(None, 1), out_axes=1)
         return jax.vmap(cols)(K, V)
-    return jnp.einsum("snk,skr->snr", K, V)
+    return einsum("snk,skr->snr", K, V)
 
 
 def implicit_dual_apply_many(L, Btp: jax.Array, lambda_ids: jax.Array,
                              n_lambda: int, Lam: jax.Array) -> jax.Array:
     """Eq. 11 on a column stack: SPMM + multi-RHS TRSM + SPMM."""
     p_loc = gather_local(Lam, lambda_ids)  # (S, m_max, n_rhs)
-    v = jnp.einsum("snm,smr->snr", Btp, p_loc)
+    v = einsum("snm,smr->snr", Btp, p_loc)
     t = solve_with_factor_many(L, v)
-    q_loc = jnp.einsum("snm,snr->smr", Btp, t)
+    q_loc = einsum("snm,snr->smr", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda)
 
 
@@ -318,9 +319,9 @@ def lumped_preconditioner_many(K, Bt: jax.Array, lambda_ids: jax.Array,
     """Lumped preconditioner on an (n_lambda, n_rhs) residual stack."""
 
     def apply_local(p):
-        v = jnp.einsum("snm,smr->snr", Bt, p)
+        v = einsum("snm,smr->snr", Bt, p)
         v = apply_stiffness_many(K, v)
-        return jnp.einsum("snm,snr->smr", Bt, v)
+        return einsum("snm,snr->smr", Bt, v)
 
     return local_dual_apply_many(apply_local, lambda_ids, n_lambda, W)
 
@@ -331,9 +332,9 @@ def dirichlet_preconditioner_many(Sb: jax.Array, Btb: jax.Array,
     """Dirichlet preconditioner on an (n_lambda, n_rhs) residual stack."""
 
     def apply_local(p):
-        v = jnp.einsum("sbm,smr->sbr", Btb, p)
-        v = jnp.einsum("sab,sbr->sar", Sb, v)
-        return jnp.einsum("sbm,sbr->smr", Btb, v)
+        v = einsum("sbm,smr->sbr", Btb, p)
+        v = einsum("sab,sbr->sar", Sb, v)
+        return einsum("sbm,sbr->smr", Btb, v)
 
     return local_dual_apply_many(apply_local, lambda_ids, n_lambda, W)
 
@@ -343,7 +344,7 @@ def dual_rhs_many(L, Btp: jax.Array, Fp: jax.Array, lambda_ids: jax.Array,
     """D = B K⁺ F − c1ᵀ for an (S, n, n_rhs) load-case stack ``Fp``
     (factor row order); ``c`` broadcasts over the column axis."""
     t = solve_with_factor_many(L, Fp)
-    q_loc = jnp.einsum("snm,snr->smr", Btp, t)
+    q_loc = einsum("snm,snr->smr", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda) - c[:, None]
 
 
@@ -363,9 +364,9 @@ def implicit_dual_apply_refined_many(L, Kreg, Btp: jax.Array,
                                      steps: int, Lam: jax.Array) -> jax.Array:
     """Eq. 11 on a column stack with refined interior solves."""
     p_loc = gather_local(Lam, lambda_ids)
-    v = jnp.einsum("snm,smr->snr", Btp, p_loc)
+    v = einsum("snm,smr->snr", Btp, p_loc)
     t = solve_with_factor_refined_many(L, Kreg, v, steps)
-    q_loc = jnp.einsum("snm,snr->smr", Btp, t)
+    q_loc = einsum("snm,snr->smr", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda)
 
 
@@ -374,5 +375,5 @@ def dual_rhs_refined_many(L, Kreg, Btp: jax.Array, Fp: jax.Array,
                           steps: int, c: jax.Array) -> jax.Array:
     """D = B K⁺ F − c1ᵀ with refined (f64-accurate) interior solves."""
     t = solve_with_factor_refined_many(L, Kreg, Fp, steps)
-    q_loc = jnp.einsum("snm,snr->smr", Btp, t)
+    q_loc = einsum("snm,snr->smr", Btp, t)
     return scatter_dual(q_loc, lambda_ids, n_lambda) - c[:, None]

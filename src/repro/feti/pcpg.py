@@ -127,6 +127,7 @@ def pcpg(
     max_iter: int = 500,
     mesh=None,
     history: bool = False,
+    atol=0.0,
 ) -> PCPGResult:
     """Solve P F λ = P d on the affine space λ⁰ + Ker(Gᵀ).
 
@@ -146,6 +147,12 @@ def pcpg(
     recurrence — the λ/r/p updates are the same operations — so the
     returned ``lam`` is bit-identical to the ``history=False`` program;
     ``history=False`` carries nothing extra at all.
+
+    ``atol`` (a traced scalar is fine) floors the stopping threshold:
+    the loop ends once ``‖P r‖ <= max(tol·‖P r⁰‖, atol)``. A
+    defect-correction solve passes the outer loop's target here, so it
+    stops once the correction is good enough instead of chasing a
+    relative reduction its operator's rounding cannot reach.
     """
     if precondition is None:
         precondition = _identity
@@ -166,7 +173,7 @@ def pcpg(
     z0 = project(precondition(w0))
     zeta0 = jnp.vdot(z0, w0)
     norm_w0 = jnp.linalg.norm(w0)
-    atol = tol * jnp.maximum(norm_w0, 1e-30)
+    atol = jnp.maximum(tol * jnp.maximum(norm_w0, 1e-30), atol)
 
     def cond(carry):
         w_norm, k = carry[4], carry[5]
@@ -210,6 +217,7 @@ def pcpg_many(
     max_iter: int = 500,
     mesh=None,
     history: bool = False,
+    atol=0.0,
 ) -> PCPGManyResult:
     """Block-batched PCPG over an (n_lambda, n_rhs) multiplier stack with
     per-column stopping.
@@ -245,6 +253,9 @@ def pcpg_many(
     ``block_iterations`` are zero — trim host-side). The buffer is
     write-only with respect to the CG recurrences, so ``lam`` is
     bit-identical to the ``history=False`` program.
+
+    ``atol`` (scalar or per column) floors each column's stopping
+    threshold, as in :func:`pcpg`.
     """
     if precondition is None:
         precondition = _identity
@@ -271,7 +282,7 @@ def pcpg_many(
     Z0 = project(precondition(W0))
     zeta0 = col_dot(Z0, W0)
     norm_w0 = col_norm(W0)
-    atol = tol * jnp.maximum(norm_w0, 1e-30)  # (n_rhs,)
+    atol = jnp.maximum(tol * jnp.maximum(norm_w0, 1e-30), atol)  # (n_rhs,)
     active0 = norm_w0 > atol  # already-converged (e.g. zero-load padding)
     #                           columns never enter the loop: 0 iterations
 

@@ -23,6 +23,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import einsum, mm
+
 __all__ = ["CoarseProblem", "build_coarse_problem", "coarse_g_e",
            "coarse_e", "coarse_e_many", "coarse_factor"]
 
@@ -38,11 +40,11 @@ def coarse_g_e(Bt: jax.Array, f: jax.Array, R: jax.Array,
     per-shard body in :mod:`repro.feti.sharded` (where ``Bt`` is that
     device's slice of subdomains)."""
     S, _, k = R.shape
-    vals = jnp.einsum("snm,snk->smk", Bt, R)  # (S, m_max, k)
+    vals = einsum("snm,snk->smk", Bt, R)  # (S, m_max, k)
     s_idx = jnp.broadcast_to(jnp.arange(S)[:, None], lambda_ids.shape)
     G = jnp.zeros((n_lambda + 1, S, k), Bt.dtype)
     G = G.at[lambda_ids, s_idx].add(vals)[:-1].reshape(n_lambda, S * k)
-    e = jnp.einsum("sn,snk->sk", f, R).reshape(S * k)
+    e = einsum("sn,snk->sk", f, R).reshape(S * k)
     return G, e
 
 
@@ -52,14 +54,14 @@ def coarse_e(f: jax.Array, R: jax.Array) -> jax.Array:
     through a cached coarse problem (G and its factor are load-free).
     Same einsum as :func:`coarse_g_e`, so the result is bit-identical."""
     S, _, k = R.shape
-    return jnp.einsum("sn,snk->sk", f, R).reshape(S * k)
+    return einsum("sn,snk->sk", f, R).reshape(S * k)
 
 
 def coarse_e_many(F: jax.Array, R: jax.Array) -> jax.Array:
     """e = RᵀF for an (S, n, n_rhs) load-case stack → (S·k, n_rhs),
     subdomain-major rows matching G's column order."""
     S, _, k = R.shape
-    return jnp.einsum("snr,snk->skr", F, R).reshape(S * k, F.shape[2])
+    return einsum("snr,snk->skr", F, R).reshape(S * k, F.shape[2])
 
 
 def coarse_factor(G: jax.Array) -> jax.Array:
@@ -110,6 +112,7 @@ def coarse_factor(G: jax.Array) -> jax.Array:
     return (Rq * sign[:, None]).T
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class CoarseProblem:
     G: jax.Array  # (n_lambda, S·k)
@@ -130,7 +133,7 @@ class CoarseProblem:
 
     def project(self, x: jax.Array) -> jax.Array:
         """P x = x − G (GᵀG)⁻¹ Gᵀ x."""
-        return x - self.G @ self.solve_coarse(self.G.T @ x)
+        return x - mm(self.G, self.solve_coarse(mm(self.G.T, x)))
 
     def lambda0(self, e: jax.Array = None) -> jax.Array:
         """Feasible start: λ⁰ = G(GᵀG)⁻¹e satisfies Gᵀλ⁰ = e.
@@ -138,11 +141,11 @@ class CoarseProblem:
         ``e`` overrides the cached load moment — a (S·k,) vector or an
         (S·k, n_rhs) stack of them for new load cases (see
         :func:`coarse_e` / :func:`coarse_e_many`)."""
-        return self.G @ self.solve_coarse(self.e if e is None else e)
+        return mm(self.G, self.solve_coarse(self.e if e is None else e))
 
     def alpha(self, Flam_minus_d: jax.Array) -> jax.Array:
         """α = (GᵀG)⁻¹Gᵀ(Fλ − d): (S·k,), reshape to (S, k) per subdomain."""
-        return self.solve_coarse(self.G.T @ Flam_minus_d)
+        return self.solve_coarse(mm(self.G.T, Flam_minus_d))
 
 
 def build_coarse_problem(Bt: jax.Array, f: jax.Array, R: jax.Array,

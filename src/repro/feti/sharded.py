@@ -39,14 +39,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core.precision import einsum, mm
 from repro.feti import operator as op
 from repro.feti import projector as proj
 from repro.feti.projector import CoarseProblem, coarse_factor, coarse_g_e
 
-try:  # jax >= 0.4.35 re-exports shard_map from the top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 __all__ = [
     "AXIS",
@@ -129,8 +127,10 @@ def pad_stack(x: np.ndarray, S_pad: int, identity: bool = False) -> np.ndarray:
 
 
 def shard_stack(mesh: Mesh, x) -> jax.Array:
-    """Place a host stack on the mesh, subdomain axis sharded over AXIS."""
-    return jax.device_put(jnp.asarray(x), data_sharding(mesh))
+    """Place a stack on the mesh, subdomain axis sharded over AXIS. A host
+    array goes straight to its shards: each device receives only its own
+    slice of subdomains."""
+    return jax.device_put(x, data_sharding(mesh))
 
 
 def relabel_columns(stack: np.ndarray, col_perm: np.ndarray) -> np.ndarray:
@@ -345,7 +345,7 @@ def dual_rhs_refined(
 
     def body(L_l, Kr_l, B_l, f_l, ids_l):
         t = op.solve_with_factor_refined(L_l, Kr_l, f_l, steps)
-        q_loc = jnp.einsum("snm,sn->sm", B_l, t)
+        q_loc = einsum("snm,sn->sm", B_l, t)
         q = op.scatter_dual(q_loc, ids_l, n_lambda)
         return jax.lax.psum(q, AXIS)
 
@@ -370,7 +370,7 @@ def dual_rhs_refined_many(
 
     def body(L_l, Kr_l, B_l, F_l, ids_l):
         t = op.solve_with_factor_refined_many(L_l, Kr_l, F_l, steps)
-        q_loc = jnp.einsum("snm,snr->smr", B_l, t)
+        q_loc = einsum("snm,snr->smr", B_l, t)
         q = op.scatter_dual(q_loc, ids_l, n_lambda)
         return jax.lax.psum(q, AXIS)
 
@@ -489,7 +489,7 @@ def dual_rhs_many(
 
     def body(L_l, B_l, F_l, ids_l):
         t = op.solve_with_factor_many(L_l, F_l)
-        q_loc = jnp.einsum("snm,snr->smr", B_l, t)
+        q_loc = einsum("snm,snr->smr", B_l, t)
         q = op.scatter_dual(q_loc, ids_l, n_lambda)
         return jax.lax.psum(q, AXIS)
 
@@ -529,6 +529,7 @@ def coarse_e_many(mesh: Mesh, F: jax.Array, R: jax.Array) -> jax.Array:
 # coarse problem with column-sharded G
 # --------------------------------------------------------------------------
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class ShardedCoarseProblem(CoarseProblem):
     """Natural coarse space with G = BR column-sharded over subdomains.
@@ -542,12 +543,12 @@ class ShardedCoarseProblem(CoarseProblem):
     psum'd G·t — the same exchange pattern as the dual operator.
     """
 
-    mesh: Mesh
+    mesh: Mesh = dataclasses.field(metadata=dict(static=True))
 
     def _gt_x(self, x: jax.Array) -> jax.Array:
         """Gᵀ x: per-shard local matvec, no exchange (disjoint columns)."""
         return shard_map(
-            lambda G_l, x_r: G_l.T @ x_r,
+            lambda G_l, x_r: mm(G_l.T, x_r),
             mesh=self.mesh,
             in_specs=(P(None, AXIS), P()),
             out_specs=P(AXIS),
@@ -556,7 +557,7 @@ class ShardedCoarseProblem(CoarseProblem):
     def _g_t(self, t: jax.Array) -> jax.Array:
         """G t: per-shard partial sums completed by a psum over AXIS."""
         return shard_map(
-            lambda G_l, t_l: jax.lax.psum(G_l @ t_l, AXIS),
+            lambda G_l, t_l: jax.lax.psum(mm(G_l, t_l), AXIS),
             mesh=self.mesh,
             in_specs=(P(None, AXIS), P(AXIS)),
             out_specs=P(),

@@ -16,39 +16,28 @@ from __future__ import annotations
 import dataclasses
 import time
 from functools import partial
+from types import SimpleNamespace
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.core import SchurAssemblyConfig, assembly_flops
-from repro.core.precision import tol_floor
+from repro.core.precision import einsum, tol_floor
 from repro.feti.assembly import ClusterState, preprocess_cluster
 from repro.feti.config import FetiConfig, _coerce_config
+from repro.feti import operator as oplib
+from repro.feti import projector
 from repro.feti.operator import (
-    dirichlet_preconditioner,
-    dirichlet_preconditioner_many,
-    dual_rhs,
-    dual_rhs_many,
-    dual_rhs_refined,
-    dual_rhs_refined_many,
-    explicit_dual_apply,
-    explicit_dual_apply_many,
     gather_local,
-    implicit_dual_apply,
-    implicit_dual_apply_many,
-    implicit_dual_apply_refined,
-    implicit_dual_apply_refined_many,
-    lumped_preconditioner,
-    lumped_preconditioner_many,
     solve_with_factor,
     solve_with_factor_many,
     solve_with_factor_refined,
     solve_with_factor_refined_many,
 )
 from repro.feti.pcpg import PCPGManyResult, PCPGResult, pcpg, pcpg_many
-from repro.feti.projector import build_coarse_problem, coarse_e, coarse_e_many
 from repro.fem.decomposition import FetiProblem
 from repro.obs import Telemetry, metrics
 from repro.obs.trace import use_tracer
@@ -63,6 +52,48 @@ PRECONDITIONERS = ("lumped", "dirichlet", "none")
 # floor and contracts the f64 residual by ~that floor, so a handful always
 # suffices — the cap is a stagnation guard, not a tuning knob
 _MAX_OUTER = 8
+# a correction solve stops once its residual is this fraction of the
+# outer target: the outer's f64 residual then meets the target unless the
+# storage-precision operator's error exceeds the rest
+_CORRECTION_ATOL = 0.5
+
+
+# the operator functions of both deployments, by name: repro.feti.operator
+# (and projector) for one device, repro.feti.sharded (mesh first) for a mesh
+_OPS = ("explicit_dual_apply", "explicit_dual_apply_many",
+        "implicit_dual_apply", "implicit_dual_apply_many",
+        "implicit_dual_apply_refined", "implicit_dual_apply_refined_many",
+        "lumped_preconditioner", "lumped_preconditioner_many",
+        "dirichlet_preconditioner", "dirichlet_preconditioner_many",
+        "dual_rhs", "dual_rhs_many", "dual_rhs_refined",
+        "dual_rhs_refined_many", "coarse_e", "coarse_e_many")
+
+
+def _deployment_ops(mesh) -> SimpleNamespace:
+    """:data:`_OPS` of the deployment ``mesh`` selects, with one call
+    signature: the sharded functions get ``mesh`` bound."""
+    if mesh is None:
+        return SimpleNamespace(**{
+            n: getattr(oplib, n, None) or getattr(projector, n)
+            for n in _OPS})
+    from repro.feti import sharded as shlib
+
+    return SimpleNamespace(**{n: partial(getattr(shlib, n), mesh)
+                              for n in _OPS})
+
+
+def _bound(fn: Callable, arrays: tuple, *static) -> Partial:
+    """The operator ``x -> fn(*arrays, *static, x)`` as a pytree whose
+    leaves are ``arrays``: a jitted function that takes it as an argument
+    receives the cluster state's arrays as program arguments, where a
+    jitted closure over them would bake them into the program as
+    constants (gigabytes at full size)."""
+    return Partial(lambda arrs, x: fn(*arrs, *static, x), tuple(arrays))
+
+
+def _at_dtype(dt, out_dt, op: Partial, x):
+    """``op`` applied at the storage dtype ``dt`` to a solve-dtype vector."""
+    return op(x.astype(dt)).astype(out_dt)
 
 
 @dataclasses.dataclass
@@ -126,6 +157,8 @@ class _SolutionOps:
     dual_rhs_cols: Callable  # Fp (S, n, r) -> D (n_lambda, r)
     coarse_e_vec: Callable  # f (S, n) -> e (S·k,)
     coarse_e_cols: Callable  # F (S, n, r) -> E (S·k, r)
+    factor_solve: Callable  # b (S, n) -> K_reg⁻¹ b, refined when refining
+    factor_solve_many: Callable  # B (S, n, r) -> K_reg⁻¹ B
 
 
 class FetiSolver:
@@ -236,66 +269,16 @@ class FetiSolver:
         refine = st.refine_steps
         c = jnp.asarray(prob.c, dtype=sdt)
         Bt_host = np.stack([sd.Bt for sd in prob.subdomains])
+        k = _deployment_ops(st.mesh)
+        # op(*xs) as one compiled program per operator: op by op, the
+        # factor-backed operators' unrolled block loops would dispatch (and
+        # on an accelerator, compile) hundreds of small operations per call
+        compiled = jax.jit(lambda op, *xs: op(*xs))
 
         if st.mesh is None:
-            Bt_orig = jnp.asarray(Bt_host, dtype=sdt)
-            coarse = build_coarse_problem(
-                Bt_orig, st.f, st.R, st.lambda_ids, nl
-            )
-            if self.mode == "explicit":
-                apply_F = partial(explicit_dual_apply, st.F, st.lambda_ids,
-                                  nl)
-                apply_F_many = partial(explicit_dual_apply_many, st.F,
-                                       st.lambda_ids, nl)
-            elif refine > 0:
-                # implicit + refined: the operator itself is f64-accurate
-                # (reduced-precision triangular solves wrapped in f64
-                # residual corrections), so plain PCPG reaches f64 tols
-                apply_F = partial(implicit_dual_apply_refined, st.L,
-                                  st.Kreg, st.Btp, st.lambda_ids, nl,
-                                  refine)
-                apply_F_many = partial(implicit_dual_apply_refined_many,
-                                       st.L, st.Kreg, st.Btp,
-                                       st.lambda_ids, nl, refine)
-            else:
-                apply_F = partial(implicit_dual_apply, st.L, st.Btp,
-                                  st.lambda_ids, nl)
-                apply_F_many = partial(implicit_dual_apply_many, st.L,
-                                       st.Btp, st.lambda_ids, nl)
-            if refine > 0:
-                # jitted: the defect-correction outer loop calls these
-                # eagerly several times per solve
-                apply_F_exact = jax.jit(partial(
-                    implicit_dual_apply_refined, st.L, st.Kreg, st.Btp,
-                    st.lambda_ids, nl, refine))
-                apply_F_exact_many = jax.jit(partial(
-                    implicit_dual_apply_refined_many, st.L, st.Kreg,
-                    st.Btp, st.lambda_ids, nl, refine))
-            else:
-                apply_F_exact = apply_F
-                apply_F_exact_many = apply_F_many
-            # K is packed in factor row order, so it pairs with Btp (the
-            # product B̃ K B̃ᵀ is invariant to the shared row permutation)
-            precond_args = (st.K, st.Btp, st.lambda_ids, nl)
-            precond_fn = lumped_preconditioner
-            precond_fn_many = lumped_preconditioner_many
-            dirichlet_args = (st.Sb, st.Btb, st.lambda_ids, nl)
-            dirichlet_fn = dirichlet_preconditioner
-            dirichlet_fn_many = dirichlet_preconditioner_many
-            if refine > 0:
-                dual_rhs_vec = lambda fp: dual_rhs_refined(  # noqa: E731
-                    st.L, st.Kreg, st.Btp, fp, st.lambda_ids, nl,
-                    refine, c)
-                dual_rhs_cols = lambda Fp: dual_rhs_refined_many(  # noqa: E731,E501
-                    st.L, st.Kreg, st.Btp, Fp, st.lambda_ids, nl,
-                    refine, c)
-            else:
-                dual_rhs_vec = lambda fp: dual_rhs(  # noqa: E731
-                    st.L, st.Btp, fp, st.lambda_ids, nl, c)
-                dual_rhs_cols = lambda Fp: dual_rhs_many(  # noqa: E731
-                    st.L, st.Btp, Fp, st.lambda_ids, nl, c)
-            coarse_e_vec = lambda f: coarse_e(f, st.R)  # noqa: E731
-            coarse_e_cols = lambda F: coarse_e_many(F, st.R)  # noqa: E731
+            coarse = projector.build_coarse_problem(
+                jnp.asarray(Bt_host, dtype=sdt), st.f, st.R, st.lambda_ids,
+                nl)
         else:
             from repro.feti import sharded as shlib
 
@@ -309,68 +292,52 @@ class FetiSolver:
                 st.mesh, Bt_orig, st.f, st.R, st.lambda_ids, nl,
                 S_real=st.S_real,
             )
-            if self.mode == "explicit":
-                apply_F = partial(shlib.explicit_dual_apply, st.mesh, st.F,
-                                  st.lambda_ids, nl)
-                apply_F_many = partial(shlib.explicit_dual_apply_many,
-                                       st.mesh, st.F, st.lambda_ids, nl)
-            elif refine > 0:
-                apply_F = partial(shlib.implicit_dual_apply_refined,
-                                  st.mesh, st.L, st.Kreg, st.Btp,
-                                  st.lambda_ids, nl, refine)
-                apply_F_many = partial(
-                    shlib.implicit_dual_apply_refined_many, st.mesh,
-                    st.L, st.Kreg, st.Btp, st.lambda_ids, nl, refine)
-            else:
-                apply_F = partial(shlib.implicit_dual_apply, st.mesh, st.L,
-                                  st.Btp, st.lambda_ids, nl)
-                apply_F_many = partial(shlib.implicit_dual_apply_many,
-                                       st.mesh, st.L, st.Btp,
-                                       st.lambda_ids, nl)
-            if refine > 0:
-                apply_F_exact = jax.jit(partial(
-                    shlib.implicit_dual_apply_refined, st.mesh, st.L,
-                    st.Kreg, st.Btp, st.lambda_ids, nl, refine))
-                apply_F_exact_many = jax.jit(partial(
-                    shlib.implicit_dual_apply_refined_many, st.mesh,
-                    st.L, st.Kreg, st.Btp, st.lambda_ids, nl, refine))
-            else:
-                apply_F_exact = apply_F
-                apply_F_exact_many = apply_F_many
-            precond_args = (st.mesh, st.K, st.Btp, st.lambda_ids, nl)
-            precond_fn = shlib.lumped_preconditioner
-            precond_fn_many = shlib.lumped_preconditioner_many
-            dirichlet_args = (st.mesh, st.Sb, st.Btb, st.lambda_ids, nl)
-            dirichlet_fn = shlib.dirichlet_preconditioner
-            dirichlet_fn_many = shlib.dirichlet_preconditioner_many
-            if refine > 0:
-                dual_rhs_vec = lambda fp: shlib.dual_rhs_refined(  # noqa: E731,E501
-                    st.mesh, st.L, st.Kreg, st.Btp, fp, st.lambda_ids,
-                    nl, refine, c)
-                dual_rhs_cols = lambda Fp: shlib.dual_rhs_refined_many(  # noqa: E731,E501
-                    st.mesh, st.L, st.Kreg, st.Btp, Fp, st.lambda_ids,
-                    nl, refine, c)
-            else:
-                dual_rhs_vec = lambda fp: shlib.dual_rhs(  # noqa: E731
-                    st.mesh, st.L, st.Btp, fp, st.lambda_ids, nl, c)
-                dual_rhs_cols = lambda Fp: shlib.dual_rhs_many(  # noqa: E731
-                    st.mesh, st.L, st.Btp, Fp, st.lambda_ids, nl, c)
-            coarse_e_vec = lambda f: shlib.coarse_e(  # noqa: E731
-                st.mesh, f, st.R)
-            coarse_e_cols = lambda F: shlib.coarse_e_many(  # noqa: E731
-                st.mesh, F, st.R)
+
+        if refine > 0:
+            # implicit + refined: the operator itself is f64-accurate
+            # (reduced-precision triangular solves wrapped in f64 residual
+            # corrections), so plain PCPG reaches f64 tols
+            refined = (st.L, st.Kreg, st.Btp, st.lambda_ids)
+            exact = _bound(k.implicit_dual_apply_refined, refined, nl,
+                           refine)
+            exact_many = _bound(k.implicit_dual_apply_refined_many,
+                                refined, nl, refine)
+        if self.mode == "explicit":
+            apply_F = _bound(k.explicit_dual_apply, (st.F, st.lambda_ids),
+                             nl)
+            apply_F_many = _bound(k.explicit_dual_apply_many,
+                                  (st.F, st.lambda_ids), nl)
+        elif refine > 0:
+            apply_F, apply_F_many = exact, exact_many
+        else:
+            apply_F = _bound(k.implicit_dual_apply,
+                             (st.L, st.Btp, st.lambda_ids), nl)
+            apply_F_many = _bound(k.implicit_dual_apply_many,
+                                  (st.L, st.Btp, st.lambda_ids), nl)
+        if refine > 0:
+            # compiled: the defect-correction outer loop calls these
+            # eagerly several times per solve
+            apply_F_exact = partial(compiled, exact)
+            apply_F_exact_many = partial(compiled, exact_many)
+        else:
+            apply_F_exact = apply_F
+            apply_F_exact_many = apply_F_many
 
         if self.preconditioner == "lumped":
-            precond = partial(precond_fn, *precond_args)
-            precond_many = partial(precond_fn_many, *precond_args)
+            # K is packed in factor row order, so it pairs with Btp (the
+            # product B̃ K B̃ᵀ is invariant to the shared row permutation)
+            args = (st.K, st.Btp, st.lambda_ids)
+            precond = _bound(k.lumped_preconditioner, args, nl)
+            precond_many = _bound(k.lumped_preconditioner_many, args, nl)
         elif self.preconditioner == "dirichlet":
             if st.Sb is None:
                 raise ValueError(
                     "state was preprocessed without the dirichlet stage; "
                     "construct the solver with preconditioner='dirichlet' "
                     "before preprocess()")
-            precond = partial(dirichlet_fn, *dirichlet_args)
-            precond_many = partial(dirichlet_fn_many, *dirichlet_args)
+            args = (st.Sb, st.Btb, st.lambda_ids)
+            precond = _bound(k.dirichlet_preconditioner, args, nl)
+            precond_many = _bound(k.dirichlet_preconditioner_many, args, nl)
         elif self.preconditioner == "none":
             precond = None
             precond_many = None
@@ -386,23 +353,49 @@ class FetiSolver:
             # silently upcast the whole stack to f64 compute instead.
             # (apply_F_exact and the refined implicit apply stay unwrapped:
             # they are f64-accurate by construction.)
-            def _fast(fn):
-                return lambda x: fn(x.astype(stor_dt)).astype(sdt)
-
+            fast = partial(_at_dtype, stor_dt, sdt)
             if self.mode == "explicit":
-                apply_F = _fast(apply_F)
-                apply_F_many = _fast(apply_F_many)
+                apply_F = Partial(fast, apply_F)
+                apply_F_many = Partial(fast, apply_F_many)
             if precond is not None:
-                precond = _fast(precond)
-                precond_many = _fast(precond_many)
+                precond = Partial(fast, precond)
+                precond_many = Partial(fast, precond_many)
+
+        if refine > 0:
+            dual_rhs_vec = Partial(
+                lambda L, Kreg, Btp, ids, c, fp: k.dual_rhs_refined(
+                    L, Kreg, Btp, fp, ids, nl, refine, c), *refined, c)
+            dual_rhs_cols = Partial(
+                lambda L, Kreg, Btp, ids, c, Fp: k.dual_rhs_refined_many(
+                    L, Kreg, Btp, Fp, ids, nl, refine, c), *refined, c)
+            factor_solve = Partial(
+                lambda L, Kreg, b: solve_with_factor_refined(
+                    L, Kreg, b, refine), st.L, st.Kreg)
+            factor_solve_many = Partial(
+                lambda L, Kreg, B: solve_with_factor_refined_many(
+                    L, Kreg, B, refine), st.L, st.Kreg)
+        else:
+            dual_rhs_vec = Partial(
+                lambda L, Btp, ids, c, fp: k.dual_rhs(L, Btp, fp, ids, nl,
+                                                      c),
+                st.L, st.Btp, st.lambda_ids, c)
+            dual_rhs_cols = Partial(
+                lambda L, Btp, ids, c, Fp: k.dual_rhs_many(
+                    L, Btp, Fp, ids, nl, c), st.L, st.Btp, st.lambda_ids, c)
+            factor_solve = Partial(solve_with_factor, st.L)
+            factor_solve_many = Partial(solve_with_factor_many, st.L)
 
         self._ops = _SolutionOps(
             coarse=coarse, apply_F=apply_F, apply_F_many=apply_F_many,
             apply_F_exact=apply_F_exact,
             apply_F_exact_many=apply_F_exact_many,
             precond=precond, precond_many=precond_many,
-            dual_rhs_vec=dual_rhs_vec, dual_rhs_cols=dual_rhs_cols,
-            coarse_e_vec=coarse_e_vec, coarse_e_cols=coarse_e_cols,
+            dual_rhs_vec=partial(compiled, dual_rhs_vec),
+            dual_rhs_cols=partial(compiled, dual_rhs_cols),
+            coarse_e_vec=lambda f: k.coarse_e(f, st.R),
+            coarse_e_cols=lambda F: k.coarse_e_many(F, st.R),
+            factor_solve=partial(compiled, factor_solve),
+            factor_solve_many=partial(compiled, factor_solve_many),
         )
         return self._ops
 
@@ -500,7 +493,7 @@ class FetiSolver:
             t0 = time.perf_counter()
             run = self._run(inner_tol, max_iter, history)
             with tr.span("pcpg", tol=float(inner_tol)) as sp:
-                res: PCPGResult = run(d, lam0)
+                res: PCPGResult = run(d, lam0, 0.0)
                 jax.block_until_ready(res.lam)
                 sp.set(iterations=int(res.iterations),
                        residual=float(res.residual))
@@ -527,7 +520,11 @@ class FetiSolver:
                        and wnorm < 0.5 * prev):
                     prev = wnorm
                     with tr.span("refine_outer", outer=n_outer) as sp:
-                        cres: PCPGResult = run(r, jnp.zeros_like(lam))
+                        # the correction needs to reach the target only:
+                        # past it, its f32 operator's rounding can stall the
+                        # relative test (2000 iterations at heat2d size)
+                        cres: PCPGResult = run(r, jnp.zeros_like(lam),
+                                               _CORRECTION_ATOL * target)
                         lam = lam + cres.lam
                         jax.block_until_ready(lam)
                         sp.set(iterations=int(cres.iterations))
@@ -553,12 +550,8 @@ class FetiSolver:
                 Flam = ops.apply_F_exact(lam)
                 alpha_flat = coarse.alpha(Flam - d)  # (S·k,), sd-major
                 lam_loc = gather_local(lam, st.lambda_ids)
-                rhs = fp_dev - jnp.einsum("snm,sm->sn", st.Btp, lam_loc)
-                if st.refine_steps > 0:
-                    up = solve_with_factor_refined(st.L, st.Kreg, rhs,
-                                                   st.refine_steps)
-                else:
-                    up = solve_with_factor(st.L, rhs)
+                rhs = fp_dev - einsum("snm,sm->sn", st.Btp, lam_loc)
+                up = ops.factor_solve(rhs)
                 # force the device work BEFORE the clock is read: the
                 # triangular solves dispatch asynchronously and numpy's
                 # implicit transfer in _recover_u would otherwise charge
@@ -596,18 +589,20 @@ class FetiSolver:
         1-column :meth:`solve_many` batches) traces and compiles exactly
         once per tolerance instead of once per call. The cached wrapper
         runs the same compiled program a fresh ``jax.jit`` would, so
-        results are bit-identical to the uncached form."""
+        results are bit-identical to the uncached form. Called as
+        ``run(d, lam0, atol)``; ``atol`` floors the stopping threshold
+        (:func:`repro.feti.pcpg.pcpg`)."""
         key = (float(tol), int(max_iter), bool(history))
         run = self._runs.get(key)
         if run is None:
             ops = self._solution_ops()
-            run = jax.jit(
-                lambda d_, lam0_: pcpg(
-                    ops.apply_F, ops.coarse.project, d_, lam0_,
-                    precondition=ops.precond, tol=tol, max_iter=max_iter,
-                    mesh=self.state.mesh, history=history,
+            run = partial(jax.jit(
+                lambda apply_F, coarse, precond, d_, lam0_, atol_: pcpg(
+                    apply_F, coarse.project, d_, lam0_,
+                    precondition=precond, tol=tol, max_iter=max_iter,
+                    mesh=self.state.mesh, history=history, atol=atol_,
                 )
-            )
+            ), ops.apply_F, ops.coarse, ops.precond)
             self._runs[key] = run
         return run
 
@@ -620,14 +615,13 @@ class FetiSolver:
         run = self._many_runs.get(key)
         if run is None:
             ops = self._solution_ops()
-            run = jax.jit(
-                lambda D_, Lam0_: pcpg_many(
-                    ops.apply_F_many, ops.coarse.project, D_, Lam0_,
-                    precondition=ops.precond_many, tol=tol,
-                    max_iter=max_iter, mesh=self.state.mesh,
-                    history=history,
+            run = partial(jax.jit(
+                lambda apply_F, coarse, precond, D_, Lam0_, atol_: pcpg_many(
+                    apply_F, coarse.project, D_, Lam0_,
+                    precondition=precond, tol=tol, max_iter=max_iter,
+                    mesh=self.state.mesh, history=history, atol=atol_,
                 )
-            )
+            ), ops.apply_F_many, ops.coarse, ops.precond_many)
             self._many_runs[key] = run
         return run
 
@@ -721,7 +715,7 @@ class FetiSolver:
             t0 = time.perf_counter()
             run = self._many_run(inner_tol, max_iter, history)
             with tr.span("pcpg", tol=float(inner_tol)) as sp:
-                res: PCPGManyResult = run(D, Lam0)
+                res: PCPGManyResult = run(D, Lam0, np.zeros(r_pad))
                 jax.block_until_ready(res.lam)
                 sp.set(block_iterations=int(res.block_iterations))
 
@@ -751,7 +745,9 @@ class FetiSolver:
                        and np.all(Wn <= np.maximum(0.5 * prev, targets))):
                     prev = Wn
                     with tr.span("refine_outer", outer=n_outer) as sp:
-                        cres: PCPGManyResult = run(R, jnp.zeros_like(Lam))
+                        cres: PCPGManyResult = run(
+                            R, jnp.zeros_like(Lam),
+                            _CORRECTION_ATOL * targets)
                         Lam = Lam + cres.lam
                         jax.block_until_ready(Lam)
                         sp.set(block_iterations=int(cres.block_iterations))
@@ -780,12 +776,8 @@ class FetiSolver:
                 Flam = ops.apply_F_exact_many(Lam)
                 alpha_flat = coarse.alpha(Flam - D)  # (S·k, r), sd-major
                 lam_loc = gather_local(Lam, st.lambda_ids)  # (S, m_max, r)
-                rhs = Fp_dev - jnp.einsum("snm,smr->snr", st.Btp, lam_loc)
-                if st.refine_steps > 0:
-                    up = solve_with_factor_refined_many(st.L, st.Kreg, rhs,
-                                                        st.refine_steps)
-                else:
-                    up = solve_with_factor_many(st.L, rhs)
+                rhs = Fp_dev - einsum("snm,smr->snr", st.Btp, lam_loc)
+                up = ops.factor_solve_many(rhs)
                 # force the device work BEFORE the clock is read: the
                 # triangular solves dispatch asynchronously and numpy's
                 # implicit transfer in _recover_u would otherwise charge

@@ -126,8 +126,9 @@ def stepped_trsm_packed(L, B: jax.Array, meta: SteppedMeta,
     with jax.named_scope("pallas:stepped_trsm_packed"):
         Y = stepped_trsm_packed_pallas(
             Linv, L.values,
-            jnp.asarray(index.rowptr), jnp.asarray(index.cols),
-            Bp, starts, bs=bs, bm=bm, interpret=interpret)
+            jnp.asarray(index.rowptr), jnp.asarray(index.rows),
+            jnp.asarray(index.cols), Bp, starts, bs=bs, bm=bm,
+            interpret=interpret)
     return Y[:n, :m]
 
 
@@ -168,8 +169,9 @@ def stepped_trsm_syrk(L, B: jax.Array, meta: SteppedMeta,
         with jax.named_scope("pallas:stepped_trsm_syrk_packed"):
             Fl = stepped_trsm_syrk_packed_pallas(
                 Linv, L.values,
-                jnp.asarray(index.rowptr), jnp.asarray(index.cols),
-                Bp, starts, bs=bs, bm=bm, interpret=interpret)
+                jnp.asarray(index.rowptr), jnp.asarray(index.rows),
+                jnp.asarray(index.cols), Bp, starts, bs=bs, bm=bm,
+                interpret=interpret)
     else:
         n_pad = -(-n // bs) * bs
         Lp = _pad_to(L, n_pad, n_pad)

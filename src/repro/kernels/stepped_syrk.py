@@ -3,13 +3,16 @@
 Computes the lower block triangle of ``F = Yᵀ Y`` for a stepped Y. TPU
 adaptation (DESIGN.md §2):
 
-  * The *output splitting* becomes the 2-D Pallas **grid** over (bm × bm)
-    output tiles; upper-triangle programs short-circuit to zero (the same
+  * The *output splitting* becomes the first two Pallas **grid** axes over
+    (bm × bm) output tiles; upper-triangle tiles write zeros (the same
     schedule a causal-attention kernel uses to skip fully-masked blocks).
-  * The *k-dimension reduction* is the dynamic lower bound of the k loop:
-    tile (I, J≤I) accumulates only from input row-blocks at or below the
-    pivot of column stripe I (``start_block[I]``) — the zero region above
-    the pivots is never read.
+  * The *k-dimension reduction* is the third grid axis over (bs, bm) row
+    blocks of the two Y stripes, accumulated in an f32 VMEM scratch. Tile
+    (I, J≤I) accumulates only from row blocks at or below the pivot of
+    column stripe I (``start_block[I]``): steps above it re-point their
+    blocks at the start row, so the zero region above the pivots is never
+    read, and upper-triangle tiles keep the previous step's blocks, so
+    they fetch nothing.
   * Accumulation is in fp32 (MXU native) regardless of the storage dtype.
 
 ops.py mirrors the strict lower blocks to the upper triangle afterwards;
@@ -24,36 +27,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import (
+    acc_dtype,
+    check_dtype,
+    compiler_params,
+    dot_tn,
+    i32,
+)
+
 __all__ = ["stepped_syrk_pallas"]
 
 
-def _acc_dtype(dtype):
-    return jnp.float32 if dtype in (jnp.bfloat16, jnp.float16, jnp.float32) else dtype
-
-
-def _syrk_kernel(meta_ref, yi_ref, yj_ref, out_ref, *, bs: int, nb: int, bm: int):
+def _syrk_kernel(starts_ref, yi_ref, yj_ref, out_ref, acc_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    acc_t = _acc_dtype(out_ref.dtype)
+    k = pl.program_id(2)
+    acc_t = acc_dtype(out_ref.dtype)
 
-    @pl.when(j > i)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j <= i)
-    def _():
-        start = meta_ref[i]  # pivots sorted => tile (i, j<=i) starts at i's pivot
+    # pivots sorted => tile (i, j<=i) starts at stripe i's pivot
+    @pl.when(jnp.logical_and(j <= i, k >= starts_ref[i]))
+    def _accumulate():
+        acc_ref[...] += dot_tn(yi_ref[...], yj_ref[...], acc_t)
 
-        def body(k, acc):
-            rk = pl.ds(k * bs, bs)
-            yi = yi_ref[rk, :]
-            yj = yj_ref[rk, :]
-            return acc + jnp.dot(yi.T, yj, preferred_element_type=acc_t)
-
-        acc = jax.lax.fori_loop(
-            start, nb, body, jnp.zeros((bm, bm), acc_t)
-        )
-        out_ref[...] = acc.astype(out_ref.dtype)
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _store():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "bm", "interpret"))
@@ -70,17 +72,29 @@ def stepped_syrk_pallas(
     nb, nc = n // bs, m // bm
     if start_block.shape != (nc,):
         raise ValueError(f"start_block shape {start_block.shape} != {(nc,)}")
+    check_dtype(Y.dtype, interpret)
 
-    kernel = functools.partial(_syrk_kernel, bs=bs, nb=nb, bm=bm)
-    return pl.pallas_call(
-        kernel,
-        grid=(nc, nc),
+    def row(i, j, k, st):
+        # lower tiles: k past the start (clamped for all-zero stripes);
+        # upper tiles: the last row, i.e. the previous step's block
+        lower = jnp.minimum(jnp.maximum(k, st[i]), nb - 1)
+        return jax.lax.select(j <= i, lower, i32(nb - 1))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # start_block
+        grid=(nc, nc, nb),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # start_block
-            pl.BlockSpec((n, bm), lambda i, j: (0, i)),  # Y column stripe I
-            pl.BlockSpec((n, bm), lambda i, j: (0, j)),  # Y column stripe J
+            pl.BlockSpec((bs, bm), lambda i, j, k, st: (row(i, j, k, st), i)),
+            pl.BlockSpec((bs, bm), lambda i, j, k, st: (
+                row(i, j, k, st), jnp.minimum(i, j))),
         ],
-        out_specs=pl.BlockSpec((bm, bm), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((bm, bm), lambda i, j, k, st: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bm), acc_dtype(Y.dtype))],
+    )
+    return pl.pallas_call(
+        _syrk_kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, m), Y.dtype),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(start_block, Y, Y)
+    )(start_block.astype(jnp.int32), Y, Y)

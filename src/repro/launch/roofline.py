@@ -50,6 +50,7 @@ class DeviceModel:
 
     Attributes:
       kind: jax platform string ("tpu" | "gpu" | "cpu").
+      name: the ``device_kind`` it models (its key in DEVICE_MODELS).
       peak_flops: sustained f64 FLOP/s (the default dtype's regime).
       mem_bw: main-memory bandwidth, B/s.
       overhead_s: per-dispatched-op launch/dispatch overhead. This is the
@@ -89,24 +90,28 @@ def _freeze(d: dict) -> Mapping[str, float]:
     return types.MappingProxyType(dict(d))
 
 
+# One model per device kind, keyed by ``jax.Device.device_kind``.
 DEVICE_MODELS = {
-    # v5e f64 is emulated through f32 passes; rough sustained figure.
-    # f32 is the native MXU input dtype (f32 accumulate), bf16 doubles it.
-    "tpu": DeviceModel("tpu", "tpu-v5e-f64", peak_flops=1.0e12,
-                       mem_bw=HW["hbm_bw"], overhead_s=2e-6,
-                       peak_flops_by_dtype=_freeze(
-                           {"f64": 1.0e12, "f32": 98.5e12,
-                            "bf16": HW["peak_flops"]})),
-    # A100-class card (the paper's hardware): f64 non-tensor-core peak,
-    # f32 FMA peak (2x), bf16 tensor cores (16x).
-    "gpu": DeviceModel("gpu", "a100-f64", peak_flops=9.7e12,
-                       mem_bw=1.55e12, overhead_s=5e-6,
-                       peak_flops_by_dtype=_freeze(
-                           {"f64": 9.7e12, "f32": 19.5e12,
-                            "bf16": 156e12})),
+    # TPU v5e (Google Cloud docs, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM). f32 products run at Precision.HIGHEST (repro.core.precision),
+    # which XLA:TPU executes as 6 bf16 MXU passes: 197/6 TFLOP/s. f64 has
+    # no hardware path and is emulated in software; 1 TFLOP/s is an
+    # unmeasured placeholder that ranks it far below f32.
+    "TPU v5 lite": DeviceModel("tpu", "TPU v5 lite", peak_flops=1.0e12,
+                               mem_bw=HW["hbm_bw"], overhead_s=2e-6,
+                               peak_flops_by_dtype=_freeze(
+                                   {"f64": 1.0e12,
+                                    "f32": HW["peak_flops"] / 6,
+                                    "bf16": HW["peak_flops"]})),
+    # A100-class card (the paper's hardware; NVIDIA A100 datasheet): f64
+    # non-tensor-core peak, f32 FMA peak (2x), bf16 tensor cores (16x).
+    "NVIDIA A100-SXM4-80GB": DeviceModel(
+        "gpu", "NVIDIA A100-SXM4-80GB", peak_flops=9.7e12, mem_bw=1.55e12,
+        overhead_s=5e-6, peak_flops_by_dtype=_freeze(
+            {"f64": 9.7e12, "f32": 19.5e12, "bf16": 156e12})),
     # container-grade CPU; XLA:CPU per-op dispatch is comparatively heavy.
     # f32 SIMD lanes are 2x f64; bf16 has no fast path (== f32 compute).
-    "cpu": DeviceModel("cpu", "host-f64", peak_flops=5.0e10,
+    "cpu": DeviceModel("cpu", "cpu", peak_flops=5.0e10,
                        mem_bw=2.0e10, overhead_s=10e-6,
                        peak_flops_by_dtype=_freeze(
                            {"f64": 5.0e10, "f32": 1.0e11,
@@ -115,13 +120,19 @@ DEVICE_MODELS = {
 
 
 def detect_device(kind: Optional[str] = None) -> DeviceModel:
-    """Resolve a :class:`DeviceModel` from an explicit kind or jax's default
-    backend platform; unknown platforms fall back to the CPU model."""
+    """The :class:`DeviceModel` of a ``device_kind`` (default: that of
+    jax's first device). A device kind with no model is an error: ranking
+    plans with another device's numbers would hide the device."""
     if kind is None:
         import jax  # local: roofline stays importable without a backend
 
-        kind = jax.devices()[0].platform
-    return DEVICE_MODELS.get(kind, DEVICE_MODELS["cpu"])
+        kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_MODELS:
+        raise ValueError(
+            f"no device model for device_kind {kind!r}; known: "
+            f"{sorted(DEVICE_MODELS)} (add one to "
+            "repro.launch.roofline.DEVICE_MODELS)")
+    return DEVICE_MODELS[kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8, "c64": 8,

@@ -18,12 +18,16 @@ automatically), prints the selected plan with predicted-vs-measured cost,
 and cross-checks the autotuned SCs against the dense baseline of [9].
 
 ``--devices N`` shards the subdomain axis over an N-device ``("data",)``
-mesh (:mod:`repro.feti.sharded`). On hosts with fewer physical devices the
-flag forces N host-platform devices via XLA's
-``--xla_force_host_platform_device_count``, so the distributed pipeline is
-exercised end-to-end on this CPU container; combined with ``--validate``
-the sharded solution is additionally checked against a fresh single-device
-solve.
+mesh (:mod:`repro.feti.sharded`). On a CPU host the flag forces N
+host-platform devices via XLA's ``--xla_force_host_platform_device_count``,
+so the distributed pipeline runs end to end without accelerators; on an
+accelerator host with fewer than N devices it is an error. Combined with
+``--validate`` the sharded solution is additionally checked against a
+fresh single-device solve.
+
+The persistent compilation cache follows ``JAX_COMPILATION_CACHE_DIR`` when
+it is set, and otherwise lives in the checkout's ``.jax_cache``
+(:mod:`repro.launch.compile_cache`).
 """
 from __future__ import annotations
 
@@ -109,7 +113,10 @@ def main(argv=None) -> int:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    enable_compile_cache()
 
     import numpy as np
 
@@ -121,12 +128,8 @@ def main(argv=None) -> int:
 
     mesh = None
     if args.devices:
-        avail = len(jax.devices())
-        if avail < args.devices:
-            print(f"[feti] WARNING: asked for {args.devices} devices, "
-                  f"backend has {avail} (jax initialized early?); "
-                  f"using {avail}")
-        mesh = make_feti_mesh(min(args.devices, avail))
+        # make_feti_mesh raises when fewer devices exist than asked for
+        mesh = make_feti_mesh(args.devices)
         print(f"[feti] mesh: {mesh.shape['data']} device(s) on axis 'data'")
 
     fc = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)

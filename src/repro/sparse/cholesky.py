@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import mm
+
 __all__ = ["block_cholesky", "block_cholesky_flops"]
 
 
@@ -64,7 +66,7 @@ def block_cholesky(
         if mask is None:
             panel = _solve_lower_right(Lkk, W[k1:, k0:k1])
             L = L.at[k1:, k0:k1].set(panel)
-            W = W.at[k1:, k1:].add(-(panel @ panel.T))
+            W = W.at[k1:, k1:].add(-mm(panel, panel.T))
         else:
             below = [i for i in range(k + 1, nb) if mask[i, k]]
             panels = {}
@@ -79,7 +81,7 @@ def block_cholesky(
                     if j > i:
                         break
                     j0, j1, Ljk = panels[j]
-                    W = W.at[i0:i1, j0:j1].add(-(Lik @ Ljk.T))
+                    W = W.at[i0:i1, j0:j1].add(-mm(Lik, Ljk.T))
     return L
 
 

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import einsum, mm
+
 __all__ = [
     "PackedBlockIndex",
     "PackedBlocks",
@@ -159,6 +161,34 @@ class PackedBlockIndex:
         blocks = jnp.swapaxes(blocks, -3, -2)  # (..., nb, nb, bs, bs)
         return blocks[..., self.rows, self.cols, :, :]
 
+    def pack_host(self, A: np.ndarray, dtype=None,
+                  diag_identity_pad: bool = False,
+                  perm: Optional[np.ndarray] = None) -> np.ndarray:
+        """Host (numpy) twin of :meth:`pack` for ONE dense ``(n, n)``
+        matrix — of ``A[perm][:, perm]`` when ``perm`` is given. The stored
+        blocks' entries are gathered straight from ``A`` (padding reads as
+        zero), so neither the permuted nor the padded matrix is ever
+        formed: setup data is packed on the host one subdomain at a time,
+        and no dense stack is built for it or shipped to a device."""
+        A = np.asarray(A)
+        if A.shape != (self.n, self.n):
+            raise ValueError(f"expected ({self.n}, {self.n}), got {A.shape}")
+        pos = np.arange(self.n_pad)
+        src = pos if perm is None else np.concatenate(
+            [np.asarray(perm), pos[self.n:]])
+        valid = src < self.n
+        src = np.where(valid, src, 0)
+        local = np.arange(self.bs)
+        r = self.rows[:, None] * self.bs + local  # (n_blocks, bs) positions
+        c = self.cols[:, None] * self.bs + local
+        vals = A[src[r][:, :, None], src[c][:, None, :]]
+        vals = np.where(valid[r][:, :, None] & valid[c][:, None, :], vals, 0)
+        vals = vals.astype(dtype or A.dtype)
+        if diag_identity_pad and self.n_pad > self.n:
+            tail = local[self.n - (self.nb - 1) * self.bs:]
+            vals[self.diag_slots[-1], tail, tail] = 1.0
+        return vals
+
     def unpack(self, values: jax.Array) -> jax.Array:
         """Scatter (..., n_blocks, bs, bs) values back to dense (..., n, n).
 
@@ -173,6 +203,21 @@ class PackedBlockIndex:
         grid = jnp.zeros(lead + (self.nb, self.nb, self.bs, self.bs),
                          values.dtype)
         grid = grid.at[..., self.rows, self.cols, :, :].set(values)
+        dense = grid.swapaxes(-3, -2).reshape(
+            *lead, self.n_pad, self.n_pad)
+        return dense[..., : self.n, : self.n]
+
+    def unpack_symmetric(self, values: jax.Array) -> jax.Array:
+        """Dense (..., n, n) symmetric matrix whose lower block triangle is
+        stored in ``values`` (diagonal blocks whole): the strictly lower
+        blocks are mirrored into the upper triangle."""
+        lead = values.shape[:-3]
+        strict = np.flatnonzero(self.rows != self.cols)
+        grid = jnp.zeros(lead + (self.nb, self.nb, self.bs, self.bs),
+                         values.dtype)
+        grid = grid.at[..., self.rows, self.cols, :, :].set(values)
+        grid = grid.at[..., self.cols[strict], self.rows[strict], :, :].set(
+            jnp.swapaxes(values[..., strict, :, :], -1, -2))
         dense = grid.swapaxes(-3, -2).reshape(
             *lead, self.n_pad, self.n_pad)
         return dense[..., : self.n, : self.n]
@@ -251,9 +296,13 @@ def _solve_lower_right(Lkk: jax.Array, W: jax.Array) -> jax.Array:
     )
 
 
-def block_cholesky_packed(K: jax.Array, index: PackedBlockIndex
-                          ) -> PackedBlocks:
+def block_cholesky_packed(K, index: PackedBlockIndex) -> PackedBlocks:
     """Cholesky factor of SPD ``K`` computed AND stored in packed form.
+
+    ``K`` is the dense (n, n) matrix, or its lower block triangle already
+    packed in this layout (a :class:`PackedBlocks` whose diagonal tail is
+    identity-padded, e.g. from :meth:`PackedBlockIndex.pack_host`) — then
+    no dense matrix exists at any point.
 
     The numerical twin of :func:`repro.sparse.cholesky.block_cholesky` with
     ``mask=index.mask``: the diagonal/panel/update loops walk the static
@@ -262,7 +311,10 @@ def block_cholesky_packed(K: jax.Array, index: PackedBlockIndex
     dense-masked path (padding contributes exact zeros / an exact identity),
     so the stored blocks match it bit-for-bit.
     """
-    vals = index.pack(K, diag_identity_pad=True)
+    if isinstance(K, PackedBlocks):
+        vals = K.values
+    else:
+        vals = index.pack(K, diag_identity_pad=True)
     nb = index.nb
     for k in range(nb):
         dk = index.slot(k, k)
@@ -281,7 +333,7 @@ def block_cholesky_packed(K: jax.Array, index: PackedBlockIndex
                 # symbolic fill guarantees (i, j) is stored: i, j share
                 # column k, so eliminating k fills their pairing
                 vals = vals.at[index.slot(i, j)].add(
-                    -(panels[i] @ panels[j].T))
+                    -mm(panels[i], panels[j].T))
     return PackedBlocks(vals, index)
 
 
@@ -303,7 +355,7 @@ def packed_tri_solve(pb: PackedBlocks, b: jax.Array,
         for k in range(nb):
             acc = x[k]
             for j, s in index.row_slots(k):
-                acc = acc - vals[s] @ x[j]
+                acc = acc - mm(vals[s], x[j])
             xk = jax.lax.linalg.triangular_solve(
                 vals[index.slot(k, k)], acc[:, None],
                 left_side=True, lower=True)[:, 0]
@@ -313,7 +365,7 @@ def packed_tri_solve(pb: PackedBlocks, b: jax.Array,
         for k in range(nb - 1, -1, -1):
             acc = x[k]
             for i, s in index.col_slots(k):
-                acc = acc - vals[s].T @ x[i]
+                acc = acc - mm(vals[s].T, x[i])
             xk = jax.lax.linalg.triangular_solve(
                 vals[index.slot(k, k)], acc[:, None],
                 left_side=True, lower=True, transpose_a=True)[:, 0]
@@ -337,11 +389,11 @@ def packed_symm_matvec(pb: PackedBlocks, v: jax.Array) -> jax.Array:
     vb = v.reshape(nb, bs)
     out = jnp.zeros((nb, bs), v.dtype)
     out = out.at[index.rows].add(
-        jnp.einsum("bij,bj->bi", vals, vb[index.cols]))
+        einsum("bij,bj->bi", vals, vb[index.cols]))
     strict = np.flatnonzero(index.rows != index.cols)
     if strict.size:
         out = out.at[index.cols[strict]].add(
-            jnp.einsum("bji,bj->bi", vals[strict], vb[index.rows[strict]]))
+            einsum("bji,bj->bi", vals[strict], vb[index.rows[strict]]))
     return out.reshape(-1)[:n]
 
 

@@ -146,7 +146,8 @@ def test_plan_cache_respects_pattern_and_device(tmp_cache):
     p1 = plan_assembly(pat, measure="never")
     other = plan_assembly(_pattern(seed=2), measure="never")
     assert other.key != p1.key
-    gpu = plan_assembly(pat, measure="never", device=DEVICE_MODELS["gpu"])
+    gpu = plan_assembly(pat, measure="never",
+                        device=DEVICE_MODELS["NVIDIA A100-SXM4-80GB"])
     assert gpu.key != p1.key
     assert not gpu.from_cache
 
@@ -256,3 +257,105 @@ def test_plan_json_roundtrip(tmp_cache):
     q = Plan.from_json(p.to_json())
     assert q.cfg == p.cfg and q.from_cache
     assert dataclasses.asdict(q.cfg) == dataclasses.asdict(p.cfg)
+
+
+# ------------------------------------------ device model + TPU gating ----
+
+def test_detect_device_keys_on_device_kind():
+    """Device models are keyed by jax's device_kind; the CPU this suite
+    runs on resolves to the CPU model, and a v5e to the TPU one."""
+    assert detect_device().name == jax.devices()[0].device_kind
+    assert detect_device("cpu").kind == "cpu"
+    tpu = detect_device("TPU v5 lite")
+    assert tpu.kind == "tpu" and tpu is DEVICE_MODELS["TPU v5 lite"]
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "NVIDIA H100",
+                                  "tpu", "gpu"])
+def test_detect_device_rejects_unknown_accelerators(kind):
+    """An accelerator with no model is an error — never another device's
+    numbers (the old fallback ranked plans with the CPU model)."""
+    with pytest.raises(ValueError, match="no device model"):
+        detect_device(kind)
+
+
+def _tpu_candidates(pat, dtype, block_sizes=(128, 256)):
+    tpu = DEVICE_MODELS["TPU v5 lite"]
+    p = plan_assembly(pat, measure="never", block_sizes=block_sizes,
+                      device=tpu, cache=False, dtype=dtype)
+    from repro.core.autotune import offered_on
+
+    offered = []
+    for cfg in enumerate_space(block_sizes, interpret=False):
+        meta = build_stepped_meta(pat, block_size=cfg.block_size,
+                                  rhs_block_size=cfg.rhs_bs)
+        if offered_on(tpu, cfg, meta, None, dtype):
+            offered.append(cfg)
+    return p, offered
+
+
+def test_tpu_planner_offers_no_pallas_for_f64():
+    """Mosaic has no f64: on a TPU, f64 stages get no Pallas candidate, so
+    the plan is never a Pallas one; f32/bf16 stages get them all."""
+    pat = _pattern(n=512, m=160, seed=4)
+    p64, offered64 = _tpu_candidates(pat, "f64")
+    assert offered64 and not any(c.use_pallas for c in offered64)
+    assert not p64.cfg.use_pallas
+    n_all = len(enumerate_space((128, 256), interpret=False))
+    assert p64.candidates == len(offered64) < n_all
+    for dtype in ("f32", "bf16"):
+        _, offered = _tpu_candidates(pat, dtype)
+        assert len(offered) == n_all
+        assert any(c.use_pallas and c.fused for c in offered)
+
+
+def test_tpu_planner_offers_pallas_only_at_lane_multiples():
+    """Mosaic refuses block shapes whose lane dimension is not a multiple
+    of 128 (and the dense kernels' lane slices at bs = 64): on a TPU,
+    Pallas candidates at smaller blocks are not offered, the jnp ones
+    still are."""
+    pat = _pattern(n=512, m=160, seed=4)
+    _, offered = _tpu_candidates(pat, "f32", block_sizes=(64, 128))
+    assert any(c.use_pallas and c.block_size == 128 for c in offered)
+    assert not any(c.use_pallas and c.block_size == 64 for c in offered)
+    assert any(not c.use_pallas and c.block_size == 64 for c in offered)
+
+
+def test_tpu_planner_drops_candidates_over_vmem(monkeypatch):
+    """A Pallas candidate whose modeled VMEM working set exceeds the limit
+    the kernels ask for is not offered; the unfused kernels, whose working
+    set is one stripe, still are when the fused panel is what is too big."""
+    from repro.core import autotune
+    from repro.kernels import common
+
+    pat = _pattern(n=512, m=256, seed=5)
+    meta = build_stepped_meta(pat, block_size=128, rhs_block_size=128)
+    fused = SchurAssemblyConfig(block_size=128, use_pallas=True, fused=True,
+                                trsm_variant="rhs_split",
+                                syrk_variant="output_split", storage="dense")
+    unfused = dataclasses.replace(fused, fused=False)
+    need_fused = autotune.pallas_vmem_bytes(meta, fused, "f32")
+    need_unfused = autotune.pallas_vmem_bytes(meta, unfused, "f32")
+    assert need_unfused < need_fused
+    tpu = DEVICE_MODELS["TPU v5 lite"]
+    assert autotune.offered_on(tpu, fused, meta, None, "f32")
+    monkeypatch.setattr(common, "VMEM_LIMIT_BYTES", need_fused - 1)
+    assert not autotune.offered_on(tpu, fused, meta, None, "f32")
+    assert autotune.offered_on(tpu, unfused, meta, None, "f32")
+    monkeypatch.setattr(common, "VMEM_LIMIT_BYTES", need_unfused - 1)
+    assert not autotune.offered_on(tpu, unfused, meta, None, "f32")
+    # off-TPU nothing is gated: Pallas runs interpreted, penalized
+    assert autotune.offered_on(DEVICE_MODELS["cpu"], fused, meta, None,
+                               "f64")
+
+
+def test_vmem_model_heat2d_full_size_fits():
+    """At feti-heat-2d's real shapes every kernel's working set fits: the
+    factor streams from HBM instead of sitting whole in VMEM (a padded f32
+    factor alone is 4352² · 4 B ≈ 76 MB)."""
+    from repro.kernels.common import VMEM_LIMIT_BYTES, vmem_bytes
+
+    for kernel in ("trsm", "trsm_packed", "syrk", "fused", "fused_packed"):
+        need = vmem_bytes(kernel, 4352, 384, 128, 128, 4)
+        assert need <= VMEM_LIMIT_BYTES, (kernel, need)
+    assert vmem_bytes("fused", 4352, 384, 128, 128, 4) > 4352 * 384 * 4

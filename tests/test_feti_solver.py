@@ -124,3 +124,49 @@ def test_amortization_report():
     )
     assert rep["amortization_iterations"] == pytest.approx(10.0)
     assert rep["assembly_flops_per_subdomain"]["total"] > 0
+
+
+# --------------------------------------- host-side preprocessing inputs ----
+
+def test_host_stacks_are_packed_per_subdomain():
+    """The prep's inputs are built on the host one subdomain at a time:
+    every K stack is packed, and the packed regularized K is exactly the
+    regularized, permuted dense K."""
+    from repro.fem.regularization import fixing_dofs_regularization
+    from repro.feti.assembly import host_stacks, make_cluster_preprocessor
+
+    prob = decompose_problem("heat", 2, (2, 2), (4, 4))
+    fc = FetiConfig(schur=SchurAssemblyConfig(block_size=8), dtype="f32")
+    static, _ = make_cluster_preprocessor(prob, fc)
+    st = host_stacks(prob, static, fc)
+    index, perm = static["index"], static["node_perm"]
+    S = prob.n_subdomains
+    shape = (S, index.n_blocks, index.bs, index.bs)
+    assert st["Kp"].shape == st["K"].shape == shape
+    assert st["Kp"].dtype == st["K"].dtype == np.float32
+    assert st["Kreg"].dtype == np.float64  # refinement's f64 matrix
+    for i, sd in enumerate(prob.subdomains):
+        Kreg = fixing_dofs_regularization(sd.K, sd.fixing_dofs)
+        np.testing.assert_array_equal(
+            st["Kreg"][i], index.pack_host(Kreg[perm][:, perm]))
+        np.testing.assert_array_equal(
+            st["K"][i], index.pack_host(sd.K[perm][:, perm], np.float32))
+    assert host_stacks(prob, static, fc.replace(dtype="f64"))["Kreg"] is None
+
+
+def test_prep_in_subdomain_chunks_matches_batched(monkeypatch):
+    """f64 stacks on a TPU preprocess one subdomain at a time (XLA:TPU's
+    emulated f64 products need temporaries that grow with the batch); the
+    chunked program computes what the batched one does."""
+    import repro.feti.assembly as asm
+
+    prob = decompose_problem("elasticity", 2, (2, 2), (3, 3))
+    fc = FetiConfig(schur=SchurAssemblyConfig(block_size=4),
+                    preconditioner="dirichlet")
+    assert asm.subdomain_chunk(fc, 4) == 4  # batched here (not a TPU)
+    ref = asm.preprocess_cluster(prob, fc)
+    monkeypatch.setattr(asm, "subdomain_chunk", lambda fc, S: 1)
+    got = asm.preprocess_cluster(prob, fc)
+    for a, b in ((got.L, ref.L), (got.F, ref.F), (got.Sb, ref.Sb)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=0, atol=1e-12)

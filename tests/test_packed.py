@@ -449,3 +449,48 @@ def test_sharded_packed_state_is_packed(prob2d):
     for s in range(st.S_real, st.S):
         np.testing.assert_allclose(L_dense[s], np.eye(L_dense.shape[1]),
                                    rtol=0, atol=1e-12)
+
+
+# --------------------------------------------------------------------------
+# host-side packing (setup data never densified as a stack, nor on device)
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n,bs", [(37, 8), (40, 8), (25, 16)])
+def test_pack_host_matches_device_pack(n, bs):
+    """pack_host (numpy, one matrix) stores exactly what the jnp pack
+    stores, identity-padded diagonal tail included; unpack_symmetric
+    recovers the whole symmetric matrix from the lower block triangle."""
+    A = random_banded_spd(n, 5, np.random.default_rng(n))
+    mask = block_symbolic_cholesky(block_pattern(np.abs(A) > 0, bs))
+    index = PackedBlockIndex.from_mask(mask, n, bs)
+    for pad in (False, True):
+        host = index.pack_host(A, np.float64, diag_identity_pad=pad)
+        dev = np.asarray(index.pack(jnp.asarray(A), diag_identity_pad=pad))
+        assert isinstance(host, np.ndarray)
+        np.testing.assert_array_equal(host, dev)
+    np.testing.assert_array_equal(
+        np.asarray(index.unpack_symmetric(jnp.asarray(
+            index.pack_host(A)))), A)
+    f32 = index.pack_host(A, np.float32)
+    assert f32.dtype == np.float32
+    np.testing.assert_array_equal(f32, index.pack_host(A).astype(np.float32))
+    # perm packs A[perm][:, perm] without forming it
+    perm = np.random.default_rng(n + 1).permutation(n)
+    np.testing.assert_array_equal(
+        index.pack_host(A, perm=perm, diag_identity_pad=True),
+        index.pack_host(A[perm][:, perm], diag_identity_pad=True))
+
+
+def test_packed_cholesky_from_packed_input():
+    """block_cholesky_packed on an already-packed matrix (the form the
+    preprocessor now streams) equals factorizing the dense matrix."""
+    n, bs = 40, 8
+    A = random_banded_spd(n, 6, np.random.default_rng(3))
+    mask = block_symbolic_cholesky(block_pattern(np.abs(A) > 0, bs))
+    index = PackedBlockIndex.from_mask(mask, n, bs)
+    ref = block_cholesky_packed(jnp.asarray(A), index)
+    vals = jnp.asarray(index.pack_host(A, diag_identity_pad=True))
+    got = block_cholesky_packed(PackedBlocks(vals, index), index)
+    np.testing.assert_array_equal(np.asarray(got.values),
+                                  np.asarray(ref.values))

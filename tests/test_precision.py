@@ -15,6 +15,7 @@ dtype-blind bugs this issue fixed:
 """
 import warnings
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -99,7 +100,7 @@ def test_feticonfig_precision_members():
 def test_device_model_per_dtype_peaks():
     from repro.launch.roofline import DEVICE_MODELS
 
-    for name in ("tpu", "gpu"):
+    for name in ("TPU v5 lite", "NVIDIA A100-SXM4-80GB"):
         dev = DEVICE_MODELS[name]
         assert dev.peak("f32") > dev.peak("f64")
         assert dev.peak("bf16") >= dev.peak("f32")
@@ -377,8 +378,42 @@ def test_assembly_cost_prices_dtype():
     pat = random_feti_like_bt(96, 40, rng) != 0
     meta = build_stepped_meta(pat, block_size=16)
     cfg = SchurAssemblyConfig(block_size=16)
-    dev = DEVICE_MODELS["gpu"]
+    dev = DEVICE_MODELS["NVIDIA A100-SXM4-80GB"]
     c64 = assembly_cost(meta, cfg, dev, dtype="f64")
     c32 = assembly_cost(meta, cfg, dev, dtype="f32")
     assert c32["total_s"] < c64["total_s"]
     assert c32["bytes"] * 2 == c64["bytes"]
+
+
+# ------------------------------------- f64 contractions on a TPU ----------
+
+@pytest.mark.parametrize("subscripts,shapes", [
+    ("snm,sm->sn", [(3, 7, 5), (3, 5)]),
+    ("snm,sn->sm", [(3, 7, 5), (3, 7)]),
+    ("snm,snk->smk", [(3, 7, 5), (3, 7, 2)]),
+    ("snm,smr->snr", [(3, 7, 5), (3, 5, 4)]),
+    ("bji,bj->bi", [(6, 4, 4), (6, 4)]),
+    ("sab,sb->sa", [(3, 5, 5), (3, 5)]),
+])
+def test_f64_contractions_on_tpu_route(subscripts, shapes, monkeypatch):
+    """On a TPU, f64 matrix-vector contractions run as an elementwise
+    product + sum (XLA:TPU's emulated f64 dot needs temporaries several
+    times its operands); the route computes the same contraction."""
+    from repro.core import precision
+
+    rng = np.random.default_rng(len(subscripts))
+    a, b = (jnp.asarray(rng.normal(size=s)) for s in shapes)
+    ref = jnp.einsum(subscripts, a, b)
+    assert precision.einsum(subscripts, a, b).dtype == ref.dtype
+    monkeypatch.setattr(precision, "_f64_on_tpu",
+                        lambda *xs: jnp.result_type(*xs) == jnp.float64)
+    got = precision.einsum(subscripts, a, b)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-14, atol=1e-14)
+    # matmul-shaped contractions (index space >> operands) keep the dot
+    assert precision._elementwise_einsum(
+        "ij,jk->ik", jnp.ones((64, 64)), jnp.ones((64, 64))) is None
+    G = jnp.asarray(rng.normal(size=(9, 4)))
+    x = jnp.asarray(rng.normal(size=(4,)))
+    np.testing.assert_allclose(np.asarray(precision.mm(G, x)),
+                               np.asarray(G @ x), rtol=1e-14, atol=1e-14)

@@ -86,17 +86,28 @@ def test_shared_factor_bit_identical_to_two_pipelines():
     dperm = split.dperm
     assert np.array_equal(st.node_perm, dperm)
 
+    # Both reference pipelines run compiled (jax.jit), like the prep they
+    # are compared with: XLA compiles a whole program with other roundings
+    # than op-by-op dispatch (on jax 0.9.0 the eager assembly differs from
+    # the jitted one by one ulp), so only compiled-vs-compiled can be
+    # compared bit for bit.
+
     # pipeline 1 (dual): factorize regularized K in the same dperm order,
     # assemble F with the same metadata — the pre-graph computation
     Kreg = np.stack([fixing_dofs_regularization(sd.K, sd.fixing_dofs)
                      for sd in prob.subdomains])
     Kp = jnp.asarray(Kreg[:, dperm][:, :, dperm])
-    L_ref = jax.vmap(
-        lambda A: block_cholesky(A, cfg.block_size, mask=st.block_mask))(Kp)
     Btp = jnp.asarray(np.stack([sd.Bt[dperm] for sd in prob.subdomains],
                                dtype=np.float64))
-    F_ref = batched_assemble(L_ref, Btp, st.col_perm, st.inv_col_perm,
-                             st.env, cfg, st.block_mask)
+
+    @jax.jit
+    def dual(Kp, Btp):
+        L = jax.vmap(lambda A: block_cholesky(A, cfg.block_size,
+                                              mask=st.block_mask))(Kp)
+        return batched_assemble(L, Btp, st.col_perm, st.inv_col_perm,
+                                st.env, cfg, st.block_mask)
+
+    F_ref = dual(Kp, Btp)
 
     # pipeline 2 (dirichlet): its OWN interior factorization of the
     # unregularized K_ii (shared=False assembler), same symbolic products
@@ -105,8 +116,8 @@ def test_shared_factor_bit_identical_to_two_pipelines():
     Kd = jnp.asarray(np.stack(
         [sd.K[dperm][:, dperm] for sd in prob.subdomains]))
     Zb = jnp.asarray(dirlib.own_boundary_masks(prob, split))
-    Sb_ref = jax.vmap(dirlib.restrict_own_boundary)(
-        jax.vmap(d_assemble)(Kd), Zb)
+    Sb_ref = jax.jit(lambda Kd, Zb: jax.vmap(dirlib.restrict_own_boundary)(
+        jax.vmap(d_assemble)(Kd), Zb))(Kd, Zb)
 
     assert np.array_equal(np.asarray(st.F), np.asarray(F_ref))
     assert np.array_equal(np.asarray(st.Sb), np.asarray(Sb_ref))
