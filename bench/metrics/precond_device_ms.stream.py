@@ -1,0 +1,9 @@
+"""Device milliseconds per load case of the operations under the named
+scope ``feti:precond``: the preconditioner, as the PCPG loops apply it."""
+
+
+def read(run):
+    if run.trace is None or run.mix.cluster != "once":
+        return None
+    t = run.trace.scope_time(("feti:precond",))
+    return 1e3 * t / run.trace.requests if t > 0 else None

@@ -53,7 +53,7 @@ from repro.fem.meshgen import structured_mesh
 from repro.feti import dirichlet as dirlib
 from repro.feti import sharded as shlib
 from repro.feti.config import FetiConfig, _coerce_config, as_feti_config
-from repro.obs.trace import annotation, current_tracer
+from repro.obs.trace import current_tracer
 from repro.sparse import (
     block_pattern,
     block_symbolic_cholesky,
@@ -729,7 +729,8 @@ def preprocess_cluster(problem: FetiProblem, config=None,
     split = static["split"]
     share = static["share"]
 
-    stacks = host_stacks(problem, static, fc)
+    with tr.span("host_stacks"):
+        stacks = host_stacks(problem, static, fc)
     Kp, Btp, K_vals = stacks["Kp"], stacks["Btp"], stacks["K"]
     Kreg_vals = stacks["Kreg"]
     Kd, Btb, Zb = stacks.get("Kd"), stacks.get("Btb"), stacks.get("Zb")
@@ -783,7 +784,7 @@ def preprocess_cluster(problem: FetiProblem, config=None,
     # child spans attribute wall time by syncing each stage's outputs in
     # graph order (the dirichlet span measures the tail the boundary
     # stage adds beyond the dual factor + F̃)
-    with tr.span("prep"), annotation("feti.prep"):
+    with tr.span("prep"):
         if dirichlet:
             Btb_j = to_dev(Btb)
             with tr.span("stage:dual") as sp:

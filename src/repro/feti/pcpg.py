@@ -3,7 +3,10 @@
 Jittable lax.while_loop implementation; the dual operator F, the projector
 P and the preconditioner M⁻¹ are injected as closures, so the same loop
 serves implicit/explicit operators, single-host batched or mesh-sharded
-deployments.
+deployments. Each closure runs under a named scope of its own
+(``feti:dual_apply``, ``feti:precond``, ``feti:project``), so a device
+trace attributes every operation of the loop to the operator it belongs
+to, whichever deployment built it.
 """
 from __future__ import annotations
 
@@ -18,7 +21,23 @@ from repro.core.precision import dtype_name, tol_floor
 from repro.obs import metrics
 
 __all__ = ["PCPGResult", "PCPGManyResult", "pcpg", "pcpg_many",
-           "TolClampState", "reset_tol_clamp_warnings"]
+           "TolClampState", "reset_tol_clamp_warnings", "scoped",
+           "DUAL_APPLY_SCOPE", "PRECOND_SCOPE", "PROJECT_SCOPE"]
+
+DUAL_APPLY_SCOPE = "feti:dual_apply"
+PRECOND_SCOPE = "feti:precond"
+PROJECT_SCOPE = "feti:project"
+
+
+def scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` called under the named scope ``name`` (metadata only: the
+    operations and their fusion are unchanged)."""
+
+    def call(*xs):
+        with jax.named_scope(name):
+            return fn(*xs)
+
+    return call
 
 
 class TolClampState:
@@ -117,6 +136,14 @@ def _identity(x: jax.Array) -> jax.Array:
     return x
 
 
+def _scoped_operators(apply_F, project, precondition):
+    """The injected operators, each under its named scope."""
+    return (scoped(DUAL_APPLY_SCOPE, apply_F),
+            scoped(PROJECT_SCOPE, project),
+            _identity if precondition is None
+            else scoped(PRECOND_SCOPE, precondition))
+
+
 def pcpg(
     apply_F: Callable[[jax.Array], jax.Array],
     project: Callable[[jax.Array], jax.Array],
@@ -154,8 +181,8 @@ def pcpg(
     stops once the correction is good enough instead of chasing a
     relative reduction its operator's rounding cannot reach.
     """
-    if precondition is None:
-        precondition = _identity
+    apply_F, project, precondition = _scoped_operators(
+        apply_F, project, precondition)
     if mesh is None:
         constrain = _identity
     else:
@@ -257,8 +284,8 @@ def pcpg_many(
     ``atol`` (scalar or per column) floors each column's stopping
     threshold, as in :func:`pcpg`.
     """
-    if precondition is None:
-        precondition = _identity
+    apply_F, project, precondition = _scoped_operators(
+        apply_F, project, precondition)
     if mesh is None:
         constrain = _identity
     else:

@@ -37,7 +37,13 @@ from repro.feti.operator import (
     solve_with_factor_refined,
     solve_with_factor_refined_many,
 )
-from repro.feti.pcpg import PCPGManyResult, PCPGResult, pcpg, pcpg_many
+from repro.feti.pcpg import (
+    DUAL_APPLY_SCOPE,
+    PCPGManyResult,
+    PCPGResult,
+    pcpg,
+    pcpg_many,
+)
 from repro.fem.decomposition import FetiProblem
 from repro.obs import Telemetry, metrics
 from repro.obs.trace import use_tracer
@@ -233,35 +239,21 @@ class FetiSolver:
         self._runs = {}
         self._many_runs = {}
         self.timings["preprocess_s"] = time.perf_counter() - t0
-        self._record_device_bytes()
         return self.state
-
-    def _record_device_bytes(self) -> None:
-        """Gauge the persistent device stacks: bytes per stack labeled by
-        dtype, per stage-graph node, and the total."""
-        st = self.state
-        db = st.device_bytes()
-        stacks = {"L": st.L, "K": st.K, "Btp": st.Btp, "F": st.F,
-                  "Sb": st.Sb, "Btb": st.Btb,
-                  "Kreg": getattr(st, "Kreg", None)}
-        for name, arr in stacks.items():
-            if arr is None or name not in db:
-                continue
-            # packed stacks carry their dtype on .values
-            dt = jnp.result_type(getattr(arr, "values", arr))
-            metrics.gauge("device_bytes", int(db[name]), stack=name,
-                          dtype=str(np.dtype(dt)))
-        metrics.gauge("device_bytes_total", int(db["total"]))
-        for stage, v in db.get("per_stage", {}).items():
-            metrics.gauge("device_bytes", int(v), stage=stage)
 
     # ---- solution-phase machinery, load-independent ----
     def _solution_ops(self) -> _SolutionOps:
-        """Coarse problem + operator closures, built once per state and
-        cached: the pieces of the solution phase that do NOT depend on the
-        load, so streamed load cases reuse them (and their jit caches)."""
-        if self._ops is not None:
-            return self._ops
+        """Coarse problem + operator closures, built once per state (in
+        the span ``solution_ops``, its coarse problem synced) and cached:
+        the pieces of the solution phase that do NOT depend on the load,
+        so streamed load cases reuse them (and their jit caches)."""
+        if self._ops is None:
+            with self.telemetry.tracer.span("solution_ops") as sp:
+                self._ops = self._build_solution_ops()
+                sp.sync(self._ops.coarse)
+        return self._ops
+
+    def _build_solution_ops(self) -> _SolutionOps:
         st = self.state
         prob = self.problem
         nl = prob.n_lambda
@@ -274,6 +266,14 @@ class FetiSolver:
         # factor-backed operators' unrolled block loops would dispatch (and
         # on an accelerator, compile) hundreds of small operations per call
         compiled = jax.jit(lambda op, *xs: op(*xs))
+
+        # the solver's own dual-operator applications (refinement
+        # residuals, recovery), under the scope PCPG gives its own
+        def dual_apply(op, x):
+            with jax.named_scope(DUAL_APPLY_SCOPE):
+                return op(x)
+
+        dual = jax.jit(dual_apply)
 
         if st.mesh is None:
             coarse = projector.build_coarse_problem(
@@ -317,9 +317,11 @@ class FetiSolver:
         if refine > 0:
             # compiled: the defect-correction outer loop calls these
             # eagerly several times per solve
-            apply_F_exact = partial(compiled, exact)
-            apply_F_exact_many = partial(compiled, exact_many)
+            apply_F_exact = partial(dual, exact)
+            apply_F_exact_many = partial(dual, exact_many)
         else:
+            # applied op by op: a named scope does not reach the programs
+            # an eager call dispatches
             apply_F_exact = apply_F
             apply_F_exact_many = apply_F_many
 
@@ -385,7 +387,7 @@ class FetiSolver:
             factor_solve = Partial(solve_with_factor, st.L)
             factor_solve_many = Partial(solve_with_factor_many, st.L)
 
-        self._ops = _SolutionOps(
+        return _SolutionOps(
             coarse=coarse, apply_F=apply_F, apply_F_many=apply_F_many,
             apply_F_exact=apply_F_exact,
             apply_F_exact_many=apply_F_exact_many,
@@ -397,7 +399,6 @@ class FetiSolver:
             factor_solve=partial(compiled, factor_solve),
             factor_solve_many=partial(compiled, factor_solve_many),
         )
-        return self._ops
 
     def _load_stacks(self, loads: np.ndarray):
         """Host (S_real, n, ...) load stack -> device (f, fp) arrays in
@@ -464,11 +465,11 @@ class FetiSolver:
         if self.state is None:
             self.preprocess()
         st = self.state
-        ops = self._solution_ops()
-        coarse = ops.coarse
         tr = self.telemetry.tracer
 
         with use_tracer(tr), tr.span("solve", mode=self.mode) as sp_solve:
+            ops = self._solution_ops()
+            coarse = ops.coarse
             t0 = time.perf_counter()
             with tr.span("rhs_setup"):
                 if loads is None:
@@ -591,18 +592,27 @@ class FetiSolver:
         runs the same compiled program a fresh ``jax.jit`` would, so
         results are bit-identical to the uncached form. Called as
         ``run(d, lam0, atol)``; ``atol`` floors the stopping threshold
-        (:func:`repro.feti.pcpg.pcpg`)."""
+        (:func:`repro.feti.pcpg.pcpg`).
+
+        The program is named (``pcpg_run``): the device trace's scope
+        paths and the ``jit:*`` spans then say which program ran, and the
+        name is part of the persistent compilation cache's key, which
+        leaves out debug info and with it the named scopes — a program of
+        the same operations under other scopes is another entry."""
         key = (float(tol), int(max_iter), bool(history))
         run = self._runs.get(key)
         if run is None:
             ops = self._solution_ops()
-            run = partial(jax.jit(
-                lambda apply_F, coarse, precond, d_, lam0_, atol_: pcpg(
+
+            def pcpg_run(apply_F, coarse, precond, d_, lam0_, atol_):
+                return pcpg(
                     apply_F, coarse.project, d_, lam0_,
                     precondition=precond, tol=tol, max_iter=max_iter,
                     mesh=self.state.mesh, history=history, atol=atol_,
                 )
-            ), ops.apply_F, ops.coarse, ops.precond)
+
+            run = partial(jax.jit(pcpg_run), ops.apply_F, ops.coarse,
+                          ops.precond)
             self._runs[key] = run
         return run
 
@@ -615,13 +625,16 @@ class FetiSolver:
         run = self._many_runs.get(key)
         if run is None:
             ops = self._solution_ops()
-            run = partial(jax.jit(
-                lambda apply_F, coarse, precond, D_, Lam0_, atol_: pcpg_many(
+
+            def pcpg_many_run(apply_F, coarse, precond, D_, Lam0_, atol_):
+                return pcpg_many(
                     apply_F, coarse.project, D_, Lam0_,
                     precondition=precond, tol=tol, max_iter=max_iter,
                     mesh=self.state.mesh, history=history, atol=atol_,
                 )
-            ), ops.apply_F_many, ops.coarse, ops.precond_many)
+
+            run = partial(jax.jit(pcpg_many_run), ops.apply_F_many,
+                          ops.coarse, ops.precond_many)
             self._many_runs[key] = run
         return run
 
@@ -691,11 +704,11 @@ class FetiSolver:
                                   else sol.residual_history[None]),
             )
 
-        ops = self._solution_ops()
-        coarse = ops.coarse
         tr = self.telemetry.tracer
         with use_tracer(tr), tr.span("solve", mode=self.mode,
                                      n_rhs=int(n_rhs)) as sp_solve:
+            ops = self._solution_ops()
+            coarse = ops.coarse
             t0 = time.perf_counter()
             with tr.span("rhs_setup"):
                 if r_pad > n_rhs:
@@ -838,9 +851,11 @@ class FetiSolver:
 
         Every timing argument is optional: when omitted it is filled from
         measured telemetry spans — ``t_assembly_s`` from the last
-        ``stage:dual`` span (falling back to the autotuner's measured
-        per-subdomain micro-run scaled by S), ``t_dirichlet_s`` from the
-        last ``stage:dirichlet`` span (else 0), and the CURRENT mode's
+        ``stage:dual`` span less the program builds (``jit:*`` spans)
+        inside it, which are no part of the assembly (falling back to the
+        autotuner's measured per-subdomain micro-run scaled by S),
+        ``t_dirichlet_s`` likewise from the last ``stage:dirichlet`` span
+        (else 0), and the CURRENT mode's
         per-iteration time from the last ``pcpg`` span divided by its
         iteration count. The counterpart mode's per-iteration time cannot
         be inferred from this solver's own spans and must be passed; a
@@ -872,8 +887,8 @@ class FetiSolver:
         if t_assembly_s is None:
             sp = tr.last("stage:dual")
             if sp is not None:
-                t_assembly_s = sp.duration
-                measured_from["assembly_s"] = "span:stage:dual"
+                t_assembly_s = tr.net_of_builds(sp)
+                measured_from["assembly_s"] = "span:stage:dual - jit:*"
             elif (self.plan is not None
                   and getattr(self.plan, "measured_s", None) is not None
                   and self.state is not None):
@@ -882,8 +897,8 @@ class FetiSolver:
         if t_dirichlet_s is None:
             sp = tr.last("stage:dirichlet")
             if sp is not None:
-                t_dirichlet_s = sp.duration
-                measured_from["dirichlet_s"] = "span:stage:dirichlet"
+                t_dirichlet_s = tr.net_of_builds(sp)
+                measured_from["dirichlet_s"] = "span:stage:dirichlet - jit:*"
             else:
                 t_dirichlet_s = 0.0
         if t_implicit_iter_s is None or t_explicit_iter_s is None:

@@ -6,9 +6,10 @@ synchronized, attributable time — not an ad-hoc timings dict.
 
   * :mod:`repro.obs.trace` — nested span tracer (context-manager API,
     ``block_until_ready`` at span close, Chrome-trace/JSONL export,
-    cross-module propagation via :func:`use_tracer`/:func:`current_tracer`)
+    cross-module propagation via :func:`use_tracer`/:func:`current_tracer`,
+    every span a profiler annotation, ``jit:*`` spans for program builds)
   * :mod:`repro.obs.metrics` — process-global counters/gauges (plan-cache
-    hit/miss, PCPG iterations, tolerance clamps, device bytes by dtype)
+    hit/miss, PCPG iterations, tolerance clamps)
   * :mod:`repro.obs.timing` — THE synchronized timing helper shared by the
     autotuner's measured refinement and the benchmark harness
   * :mod:`repro.obs.validate` — schema validation for the exported
@@ -24,7 +25,6 @@ from repro.obs.trace import (
     TRACE_SCHEMA_VERSION,
     Span,
     Tracer,
-    annotation,
     current_tracer,
     use_tracer,
 )
@@ -34,7 +34,6 @@ __all__ = [
     "Span",
     "Tracer",
     "Telemetry",
-    "annotation",
     "current_tracer",
     "use_tracer",
     "metrics",

@@ -2,13 +2,13 @@
 
 The pipeline's counting instruments live here — plan-cache hits/misses
 (per stage and per graph key), PCPG iterations and defect-correction
-outers, tolerance-clamp events — plus gauges for per-stack device bytes
-by dtype. One process-global default registry (like Prometheus' default
+outers, tolerance-clamp events — and gauges, which keep the last value
+set. One process-global default registry (like Prometheus' default
 registry) keeps the call sites one-liners:
 
     from repro.obs import metrics
     metrics.inc("plan_cache.stage.miss", stage="dual", dtype="f64")
-    metrics.gauge("device_bytes", 123456, stack="L", dtype="f32")
+    metrics.gauge("queue_depth", 3, stage="dual")
 
 Labels are flattened into the metric key (``name{k=v,...}`` with sorted
 label keys), so :func:`snapshot` returns plain JSON-safe dicts. Tests

@@ -28,9 +28,23 @@ tracer with :func:`use_tracer` and downstream layers (assembly, the stage
 graph, the autotuner) pick it up via :func:`current_tracer` — no tracer
 installed means every downstream span is a no-op.
 
-:func:`annotation` additionally wraps a region in
-``jax.profiler.TraceAnnotation`` so XLA-level profiles line up with our
-span names; inside jitted code use ``jax.named_scope`` directly.
+Every enabled span is also a ``jax.profiler.TraceAnnotation`` of its own
+name, so a profiler trace shows the phases on the profiler's clock next to
+the device's operations; inside jitted code use ``jax.named_scope``.
+
+Program builds are recorded by the program itself: one ``jax.monitoring``
+listener, registered at import, records a closed leaf span under the
+innermost open span of the installed tracer for every
+
+  * ``jit:trace`` — a top-level jaxpr trace (a trace nested inside another
+    is part of the outer one's time and gets no span of its own),
+  * ``jit:lower`` — a jaxpr's lowering to an MLIR module,
+  * ``jit:load`` — a backend compile served by the persistent compilation
+    cache,
+  * ``jit:compile`` — a backend compile that ran the compiler,
+
+each with the built function's name as ``fun``. With no enabled tracer
+installed the listener returns after one check.
 """
 from __future__ import annotations
 
@@ -40,16 +54,20 @@ import json
 import time
 from typing import Any, Optional
 
+import jax
+from jax._src import core as jax_core  # trace_state_clean: no public form
+
 __all__ = [
     "TRACE_SCHEMA_VERSION",
+    "BUILD_PREFIX",
     "Span",
     "Tracer",
     "current_tracer",
     "use_tracer",
-    "annotation",
 ]
 
 TRACE_SCHEMA_VERSION = 1
+BUILD_PREFIX = "jit:"  # the program-build spans: jit:trace, jit:lower, ...
 
 
 @dataclasses.dataclass
@@ -96,12 +114,13 @@ _NULL_SPAN = _NullSpan()
 class _SpanHandle:
     """Context manager for one open span on one tracer."""
 
-    __slots__ = ("_tracer", "span", "_sync")
+    __slots__ = ("_tracer", "span", "_sync", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
         self._sync: list = []
+        self._annotation = jax.profiler.TraceAnnotation(span.name)
 
     def sync(self, *values):
         """Register arrays/pytrees to ``jax.block_until_ready`` at close."""
@@ -117,13 +136,13 @@ class _SpanHandle:
         return self.span.duration
 
     def __enter__(self):
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
         if self._sync:
-            import jax
-
             jax.block_until_ready(self._sync)
+        self._annotation.__exit__(*exc)
         self._tracer._close(self.span)
         return False
 
@@ -143,17 +162,31 @@ class Tracer:
         """Open a nested span; use as a context manager."""
         if not self.enabled:
             return _NULL_SPAN
+        sp = self._add(name, time.perf_counter(), None, attrs)
+        self._stack.append(sp.index)
+        return _SpanHandle(self, sp)
+
+    def record(self, name: str, t_start: float, t_end: float,
+               **attrs) -> Optional[Span]:
+        """Record an already-closed leaf span under the innermost open
+        span (the program-build listener's entry point)."""
+        if not self.enabled:
+            return None
+        return self._add(name, t_start, t_end, attrs)
+
+    def _add(self, name: str, t_start: float, t_end: Optional[float],
+             attrs: dict) -> Span:
         sp = Span(
             name=name,
-            t_start=time.perf_counter(),
+            t_start=t_start,
+            t_end=t_end,
             depth=len(self._stack),
             parent=self._stack[-1] if self._stack else None,
             index=len(self.spans),
             attrs=attrs,
         )
         self.spans.append(sp)
-        self._stack.append(sp.index)
-        return _SpanHandle(self, sp)
+        return sp
 
     def _close(self, span: Span) -> None:
         span.t_end = time.perf_counter()
@@ -179,6 +212,23 @@ class Tracer:
     def last_duration(self, name: str) -> Optional[float]:
         sp = self.last(name)
         return None if sp is None else sp.duration
+
+    def within(self, span: Span) -> list:
+        """The spans nested, at any depth, inside ``span``."""
+        inside = {span.index}
+        out = []
+        for sp in self.spans[span.index + 1:]:
+            if sp.parent in inside:
+                inside.add(sp.index)
+                out.append(sp)
+        return out
+
+    def net_of_builds(self, span: Span) -> float:
+        """``span``'s seconds less those of the program-build spans
+        (``jit:*``) inside it: the time of the work it ran."""
+        return span.duration - sum(
+            sp.duration for sp in self.within(span)
+            if sp.name.startswith(BUILD_PREFIX))
 
     def tree(self) -> list:
         """Nested view: list of root span dicts with ``children`` lists."""
@@ -292,13 +342,45 @@ def use_tracer(tracer: Tracer):
         _ACTIVE.pop()
 
 
-def annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` for a host region, so XLA-level
-    profiles line up with our span names; a null context when the profiler
-    API (or jax itself) is unavailable."""
-    try:
-        import jax
+# -- program builds, recorded by the program --------------------------------
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_BUILD_SPANS = {
+    _TRACE_EVENT: "jit:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit:lower",
+}
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_last_cache_hit = float("-inf")  # perf_counter of the latest cache hit
+
+
+def _on_event(event: str, **_) -> None:
+    global _last_cache_hit
+    if event == _CACHE_HIT_EVENT:
+        _last_cache_hit = time.perf_counter()
+
+
+def _on_duration(event: str, secs: float, fun_name: str = "?",
+                 **_) -> None:
+    if not (_ACTIVE and _ACTIVE[-1].enabled):
+        return
+    if event == _COMPILE_EVENT:
+        name = None  # jit:load or jit:compile, by the time of the last hit
+    elif event in _BUILD_SPANS:
+        # a trace nested in another trace is part of the outer one's time
+        if event == _TRACE_EVENT and not jax_core.trace_state_clean():
+            return
+        name = _BUILD_SPANS[event]
+    else:
+        return
+    t_end = time.perf_counter()
+    t_start = t_end - secs
+    if name is None:
+        # a compile served by the persistent cache reports its hit while
+        # it runs
+        name = "jit:load" if _last_cache_hit >= t_start else "jit:compile"
+    _ACTIVE[-1].record(name, t_start, t_end, fun=fun_name)
+
+
+jax.monitoring.register_event_listener(_on_event)
+jax.monitoring.register_event_duration_secs_listener(_on_duration)

@@ -1,14 +1,19 @@
-"""Telemetry tier (ISSUE 10): the span tracer (nesting, monotonicity,
-disabled-path overhead), the metrics registry, the trace/JSONL exports and
-their schema validator, plan-cache hit/miss counters, the PCPG residual
-history (trim semantics + bit-identity of ``lam`` with history off), the
-recover-span sync regression, and the exact tolerance-clamp counter."""
+"""Telemetry tier: the span tracer (nesting, monotonicity,
+disabled-path overhead, profiler annotations), the program-build spans
+(``jit:*``), the PCPG operators' named scopes, the metrics registry, the
+trace/JSONL exports and their schema validator, plan-cache hit/miss
+counters, the PCPG residual history (trim semantics + bit-identity of
+``lam`` with history off), the recover-span sync regression, and the exact
+tolerance-clamp counter."""
 import json
+import re
+import sys
 import time
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import SchurAssemblyConfig
@@ -21,7 +26,12 @@ from repro.feti.pcpg import (
     reset_tol_clamp_warnings,
 )
 from repro.obs import Telemetry, Tracer, metrics
-from repro.obs.trace import _NULL_SPAN, current_tracer, use_tracer
+from repro.obs.trace import (
+    _NULL_SPAN,
+    BUILD_PREFIX,
+    current_tracer,
+    use_tracer,
+)
 from repro.obs.validate import (
     main as validate_main,
     validate_chrome_trace,
@@ -39,6 +49,20 @@ def prob():
 def _solver(prob):
     return FetiSolver(prob, FetiConfig(
         schur=SchurAssemblyConfig(block_size=8, rhs_block_size=8)))
+
+
+@pytest.fixture(scope="module")
+def first_solve(prob):
+    """A fresh solver after its first solve, with that solve's wall time
+    (preprocessing included)."""
+    solver = _solver(prob)
+    t0 = time.perf_counter()
+    solver.solve(tol=1e-8, max_iter=200)
+    return solver, time.perf_counter() - t0
+
+
+def _builds(spans):
+    return [sp for sp in spans if sp.name.startswith(BUILD_PREFIX)]
 
 
 # ----------------------------------------------------- span tracer ----
@@ -97,6 +121,33 @@ def test_tracer_propagation_stack():
     assert tr.spans[0].name == "downstream"
 
 
+def test_enabled_span_is_a_profiler_annotation(monkeypatch):
+    events = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("inner") as sp:
+            sp.sync(jnp.ones(2))
+    assert events == [("enter", "outer"), ("enter", "inner"),
+                      ("exit", "inner"), ("exit", "outer")]
+    events.clear()
+    off = Tracer(enabled=False)
+    with off.span("outer"):
+        pass
+    assert events == []
+
+
 def test_telemetry_enable_disable():
     tel = Telemetry()
     assert tel.enabled
@@ -106,6 +157,99 @@ def test_telemetry_enable_disable():
     with tel.tracer.span("y"):
         pass
     assert tel.tracer.last("y") is not None
+
+
+# ------------------------------------------------ program builds ----
+
+def test_first_solve_records_program_builds_and_the_second_none(
+        first_solve):
+    solver, wall = first_solve
+    tr = solver.telemetry.tracer
+    builds = _builds(tr.spans)
+    names = {sp.name for sp in builds}
+    assert "jit:trace" in names and names & {"jit:compile", "jit:load"}
+    for sp in builds:
+        assert sp.attrs["fun"]
+        # a leaf inside a phase span
+        assert sp.parent is not None
+        assert not tr.spans[sp.parent].name.startswith(BUILD_PREFIX)
+    # the solver's own programs are traced inside preprocess and solve,
+    # under their names
+    assert {tr.spans[sp.parent].name for sp in builds} >= {
+        "stage:dual", "pcpg"}
+    assert {sp.attrs["fun"] for sp in builds} >= {"prep", "pcpg_run"}
+    assert sum(sp.duration for sp in builds) <= wall
+    # the steady state rebuilds no program
+    n = len(tr.spans)
+    solver.solve(tol=1e-8, max_iter=200)
+    assert [sp.name for sp in tr.spans[n:]] == [
+        "solve", "rhs_setup", "pcpg", "recover"]
+
+
+def test_a_nested_trace_is_part_of_the_outer_one():
+    def inner(x):
+        return x * 2.0 + 1.0
+
+    inner_jit = jax.jit(inner)
+
+    def outer(x):
+        return inner_jit(x) - 3.0
+
+    x = jnp.arange(3.0)
+    tr = Tracer()
+    with use_tracer(tr), tr.span("request") as req:
+        jax.jit(outer)(x).block_until_ready()
+    traces = [sp for sp in tr.spans if sp.name == "jit:trace"]
+    assert [sp.attrs["fun"] for sp in traces] == ["outer"]
+    assert sum(sp.duration for sp in _builds(tr.spans)) <= req.duration
+
+
+def test_a_compile_is_a_load_when_the_cache_served_it():
+    tr = Tracer()
+    event = "/jax/core/compile/backend_compile_duration"
+    with use_tracer(tr), tr.span("request"):
+        jax.monitoring.record_event_duration_secs(event, 0.01,
+                                                  fun_name="f")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event_duration_secs(event, 0.01,
+                                                  fun_name="g")
+    with use_tracer(Tracer(enabled=False)):
+        jax.monitoring.record_event_duration_secs(event, 0.01,
+                                                  fun_name="h")
+    assert [(sp.name, sp.attrs["fun"]) for sp in _builds(tr.spans)] == [
+        ("jit:compile", "f"), ("jit:load", "g")]
+
+
+# ------------------------------------------------- operator scopes ----
+
+def _strip_metadata(hlo: str) -> str:
+    """Compiled HLO text without its metadata and source-frame tables."""
+    return re.sub(r", metadata=\{[^}]*\}", "", hlo[hlo.index("\n%"):])
+
+
+def _runner_lowered(solver):
+    run = solver._run(1e-8, 200)
+    ops = solver._solution_ops()
+    d = ops.dual_rhs_vec(solver.state.fp)
+    return run.func.lower(*run.args, d, ops.coarse.lambda0(), 0.0)
+
+
+def test_pcpg_runner_names_its_operator_scopes(first_solve, prob,
+                                               monkeypatch):
+    solver, _ = first_solve
+    lowered = _runner_lowered(solver)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("feti:dual_apply", "feti:precond", "feti:project"):
+        assert scope in text
+    # metadata only: without the scopes the compiled program is the same
+    scoped_hlo = _strip_metadata(lowered.compile().as_text())
+    monkeypatch.setattr(sys.modules["repro.feti.pcpg"], "scoped",
+                        lambda name, fn: fn)
+    plain = _solver(prob)
+    plain.preprocess()
+    plain_lowered = _runner_lowered(plain)
+    assert "feti:project" not in plain_lowered.as_text(debug_info=True)
+    assert _strip_metadata(plain_lowered.compile().as_text()) == scoped_hlo
 
 
 # ------------------------------------------------ exports + schema ----
@@ -354,19 +498,38 @@ def test_solver_report_structure(prob):
     names = {s["name"] for s in rep["spans"]}
     assert {"preprocess", "solve"} <= names
     solve_span = [s for s in rep["spans"] if s["name"] == "solve"][-1]
-    child_names = [c["name"] for c in solve_span["children"]]
-    assert child_names[:2] == ["rhs_setup", "pcpg"]
+    child_names = [c["name"] for c in solve_span["children"]
+                   if not c["name"].startswith(BUILD_PREFIX)]
+    assert child_names[:3] == ["solution_ops", "rhs_setup", "pcpg"]
     assert child_names[-1] == "recover"
     hist = solve_span["attrs"]["residual_history"]
     assert len(hist) == sol.iterations
     assert rep["metrics"]["counters"]["pcpg.solves"] >= 1
     assert rep["metrics"]["counters"]["pcpg.iterations"] >= sol.iterations
-    # device-bytes gauges carry dtype labels
-    assert any(k.startswith("device_bytes{")
-               for k in rep["metrics"]["gauges"])
-    assert rep["device_bytes"]["total"] > 0
+    # the persistent stacks' device bytes, per stack
+    by = rep["device_bytes"]
+    for stack in ("L", "K", "Btp", "F"):
+        assert by[stack] > 0
+    assert by["total"] >= by["L"] + by["K"] + by["Btp"] + by["F"]
     # deprecated flat view still present
     assert set(rep["timings"]) >= {"preprocess_s", "solve_s", "recover_s"}
     # the report round-trips through the chrome-trace exporter
     assert "traceEvents" in solver.telemetry.tracer.chrome_trace(
         rep["metrics"])
+
+
+# ------------------------------------------------ amortization ----
+
+def test_amortization_assembly_time_is_net_of_program_builds(first_solve):
+    solver, _ = first_solve
+    tr = solver.telemetry.tracer
+    dual = tr.last("stage:dual")
+    builds = [sp for sp in tr.within(dual)
+              if sp.name.startswith(BUILD_PREFIX)]
+    assert builds  # the prep program was built inside the span
+    rep = solver.amortization_report(t_implicit_iter_s=0.15,
+                                     t_explicit_iter_s=0.05)
+    assert rep["measured_from"]["assembly_s"] == "span:stage:dual - jit:*"
+    assert rep["assembly_s"] == pytest.approx(
+        dual.duration - sum(sp.duration for sp in builds))
+    assert 0 <= rep["assembly_s"] < dual.duration
