@@ -52,6 +52,7 @@ from repro.fem.decomposition import FetiProblem
 from repro.fem.meshgen import structured_mesh
 from repro.feti import dirichlet as dirlib
 from repro.feti import sharded as shlib
+from repro.feti import programs as proglib
 from repro.feti.config import FetiConfig, _coerce_config, as_feti_config
 from repro.obs.trace import current_tracer
 from repro.sparse import (
@@ -148,6 +149,9 @@ class ClusterState:
     #   (Kp, Btp, Kbb, Zb) -> (L, F, Sb)             + dirichlet, shared
     #                                                  interior factor
     prep: Optional[Callable] = None
+    # the structure's programs (repro.feti.programs): prep and the
+    # solver's, shared with every cluster of the same structure
+    programs: Optional[proglib.StructurePrograms] = None
     # ---- Dirichlet preconditioner stage (preconditioner="dirichlet") ----
     split: Optional[dirlib.BoundaryInteriorSplit] = None
     Sb: Optional[jax.Array] = None  # (S, n_b, n_b) primal boundary SCs
@@ -327,10 +331,19 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
     emit a ``DeprecationWarning``.
 
     Returns (static, prep) where ``prep`` is jitted once per sparsity
-    pattern — the paper's symbolic/numeric split: multi-step simulations
-    recall ``prep`` with new values at zero recompiles. ``static`` carries
-    the host-side symbolic products, including the resolved per-stage
-    configs and (if autotuned) the joint :class:`GraphPlan`.
+    pattern in the process — the paper's symbolic/numeric split. The
+    symbolic phase runs for every call; the jitted program is kept
+    (:mod:`repro.feti.programs`) by the digest of everything it bakes in
+    (the column permutations, the stepped envelope and fill masks, the
+    packed index, the resolved configs, the split, the dtypes, the mesh),
+    so a later decomposition of the same structure, whatever its values,
+    gets the program an earlier one built and traces nothing. Multi-step
+    simulations recall ``prep`` with new values at zero recompiles.
+    ``static`` carries the host-side symbolic products, including the
+    resolved per-stage configs, (if autotuned) the joint
+    :class:`GraphPlan`, ``programs`` (the structure's
+    :class:`~repro.feti.programs.StructurePrograms`) and ``prep_program``:
+    ``"hit"`` when the program was an earlier one's, else ``"miss"``.
 
     Every assembly stage is declared as a :class:`StageSpec` and the set
     is planned as ONE :class:`StageGraph` when ``schur == "auto"`` — a
@@ -484,6 +497,7 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
     cp = jnp.asarray(col_perms)
     icp = jnp.asarray(inv_col_perms)
     packed = cfg.storage == "packed"
+    chunk = subdomain_chunk(fc, S) if mesh is None else S
 
     def _factorize(Kp_l):
         """Batched numerical factorization in the configured storage.
@@ -536,7 +550,6 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
                 return L, F, _dirichlet_stage(L, Kp_stack, *dir_stacks)
             return L, F
 
-        chunk = subdomain_chunk(fc, S)
         run = prep_cols if chunk >= S else _map_chunks(prep_cols, chunk)
 
         def prep(Kp_stack, Btp_stack, *dir_stacks):
@@ -591,13 +604,21 @@ def make_cluster_preprocessor(problem: FetiProblem, config=None,
         def prep(*stacks):
             return _cast_tree(inner_prep(*_cast_tree(stacks, cdt)), sdt)
 
+    # everything prep bakes in; node_perm and the planner's cost reports
+    # only feed the host stacks and the reports
+    key = proglib.digest(col_perms, inv_col_perms, env, block_mask, index,
+                         cfg, d_cfg, split, share, meta_ib, mask_ii,
+                         explicit, dirichlet, mesh, chunk, sdt, cdt,
+                         np.dtype(fc.solve_dtype))
+    programs, hit = proglib.for_structure(key, prep)
     static = dict(node_perm=node_perm, block_mask=block_mask, env=env,
                   col_perm=cp, inv_col_perm=icp, cfg=cfg, plan=plan,
                   index=index, split=split, dirichlet_cfg=d_cfg,
                   dirichlet_plan=d_plan, dirichlet_env=meta_ib,
                   dirichlet_mask=mask_ii, graph=graph, graph_plan=gplan,
-                  stages=resolved, share=share)
-    return static, jax.jit(prep)
+                  stages=resolved, share=share, programs=programs,
+                  prep_program="hit" if hit else "miss")
+    return static, programs.prep
 
 
 def _pad_packed_identity(vals: np.ndarray, S_pad: int,
@@ -721,8 +742,9 @@ def preprocess_cluster(problem: FetiProblem, config=None,
     tr = current_tracer()
     # initialization (paper's symbolic phase): host symbolic products +
     # joint stage-graph planning (the planner opens plan:* child spans)
-    with tr.span("init"):
+    with tr.span("init") as sp:
         static, prep = make_cluster_preprocessor(problem, fc)
+        sp.set(prep_program=static["prep_program"])
     cfg = static["cfg"]  # resolved when "auto"/storage override was passed
     node_perm = static["node_perm"]
     index: PackedBlockIndex = static["index"]
@@ -839,6 +861,7 @@ def preprocess_cluster(problem: FetiProblem, config=None,
         n_real=S if mesh is not None else None,
         relabeled=mesh is not None,
         prep=prep,
+        programs=static["programs"],
         split=split,
         Sb=Sb,
         Btb=Btb_j,

@@ -22,28 +22,39 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.precision import einsum, mm
 
 __all__ = ["CoarseProblem", "build_coarse_problem", "coarse_g_e",
-           "coarse_e", "coarse_e_many", "coarse_factor"]
+           "coarse_e", "coarse_e_many", "coarse_factor", "interface_kernels"]
 
 
-def coarse_g_e(Bt: jax.Array, f: jax.Array, R: jax.Array,
+def interface_kernels(subdomains) -> np.ndarray:
+    """(S, m_max, k) stack of B̃ᵢRᵢ: each subdomain's kernel basis at its
+    local multipliers, from the compact gluing record. Column j of B̃ᵢᵀ
+    holds ``b_vals[j]`` (±1; 0 for a padding column) at row ``b_rows[j]``
+    alone, so every entry is one exact product — the dense (S, n, m_max)
+    B̃ᵀ is never needed to build G."""
+    return np.stack([sd.b_vals[:, None] * sd.R[sd.b_rows]
+                     for sd in subdomains])
+
+
+def coarse_g_e(BR: jax.Array, f: jax.Array, R: jax.Array,
                lambda_ids: jax.Array, n_lambda: int):
     """G = BR columns and e = Rᵀf for a stack of subdomains.
 
-    ``R`` is (S, n, k); subdomain i contributes the k columns
+    ``BR`` is the (S, m_max, k) :func:`interface_kernels` stack and ``R``
+    the (S, n, k) kernel bases; subdomain i contributes the k columns
     scatter(lambda_ids_i, B̃ᵢ R_i), laid out subdomain-major in the
     (n_lambda, S·k) result; ``e`` is the matching (S·k,) flat Rᵀf.
     The shared body of the single-device construction below and of the
-    per-shard body in :mod:`repro.feti.sharded` (where ``Bt`` is that
+    per-shard body in :mod:`repro.feti.sharded` (where the stacks are that
     device's slice of subdomains)."""
     S, _, k = R.shape
-    vals = einsum("snm,snk->smk", Bt, R)  # (S, m_max, k)
     s_idx = jnp.broadcast_to(jnp.arange(S)[:, None], lambda_ids.shape)
-    G = jnp.zeros((n_lambda + 1, S, k), Bt.dtype)
-    G = G.at[lambda_ids, s_idx].add(vals)[:-1].reshape(n_lambda, S * k)
+    G = jnp.zeros((n_lambda + 1, S, k), BR.dtype)
+    G = G.at[lambda_ids, s_idx].add(BR)[:-1].reshape(n_lambda, S * k)
     e = einsum("sn,snk->sk", f, R).reshape(S * k)
     return G, e
 
@@ -148,13 +159,13 @@ class CoarseProblem:
         return self.solve_coarse(mm(self.G.T, Flam_minus_d))
 
 
-def build_coarse_problem(Bt: jax.Array, f: jax.Array, R: jax.Array,
+def build_coarse_problem(BR: jax.Array, f: jax.Array, R: jax.Array,
                          lambda_ids: jax.Array, n_lambda: int) -> CoarseProblem:
     """Assemble G = BR (R = stacked kernel bases) and e = Rᵀf.
 
-    ``Bt`` and ``R`` must share a row (DOF) order — any consistent one
-    works, since the shared permutation drops out of B̃ᵢ R_i; we pass the
-    original-order B̃ᵀ and R.
+    ``BR`` is the (S, m_max, k) :func:`interface_kernels` stack; ``f`` and
+    ``R`` must share a row (DOF) order — we pass the original-order load
+    and kernel stacks.
     """
-    G, e = coarse_g_e(Bt, f, R, lambda_ids, n_lambda)
+    G, e = coarse_g_e(BR, f, R, lambda_ids, n_lambda)
     return CoarseProblem(G=G, GtG_chol=coarse_factor(G), e=e)

@@ -583,7 +583,7 @@ class ShardedCoarseProblem(CoarseProblem):
 
 def build_coarse_problem(
     mesh: Mesh,
-    Bt: jax.Array,
+    BR: jax.Array,
     f: jax.Array,
     R: jax.Array,
     lambda_ids: jax.Array,
@@ -592,8 +592,11 @@ def build_coarse_problem(
 ) -> ShardedCoarseProblem:
     """Assemble G = BR and e = Rᵀf from subdomain-sharded (padded) stacks.
 
-    ``R`` is the (S_pad, n, k) kernel-basis stack (zero for padding).
-    Padded subdomains have zero B̃ᵀ and zero load, so their G columns and
+    ``BR`` is the (S_pad, m_max, k)
+    :func:`repro.feti.projector.interface_kernels` stack, multiplier
+    columns relabeled like the state's; ``R`` is the (S_pad, n, k)
+    kernel-basis stack (zero for padding).
+    Padded subdomains have zero B̃R and zero load, so their G columns and
     e entries are exactly zero; the QR-derived coarse factor
     (:func:`repro.feti.projector.coarse_factor`, computed once here —
     GSPMD gathers the sharded columns for the setup-only QR) gives those
@@ -604,15 +607,15 @@ def build_coarse_problem(
     nothing before them).
     """
 
-    def body(Bt_l, f_l, R_l, ids_l):
-        return coarse_g_e(Bt_l, f_l, R_l, ids_l, n_lambda)
+    def body(BR_l, f_l, R_l, ids_l):
+        return coarse_g_e(BR_l, f_l, R_l, ids_l, n_lambda)
 
     G, e = shard_map(
         body,
         mesh=mesh,
         in_specs=(P(AXIS),) * 4,
         out_specs=(P(None, AXIS), P(AXIS)),
-    )(Bt, f, R, lambda_ids)
+    )(BR, f, R, lambda_ids)
 
     chol = jax.device_put(coarse_factor(G), replicated_sharding(mesh))
     e = jax.device_put(e, replicated_sharding(mesh))

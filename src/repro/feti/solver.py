@@ -75,6 +75,21 @@ _OPS = ("explicit_dual_apply", "explicit_dual_apply_many",
         "dual_rhs_refined_many", "coarse_e", "coarse_e_many")
 
 
+@dataclasses.dataclass(frozen=True)
+class _Call:
+    """``fn(*static, *args)``, compared and hashed by value: the static
+    data of an operator pytree. A program that takes the operator as an
+    argument then serves every solver that builds an equal one, where a
+    lambda or ``functools.partial`` (compared by identity) would make
+    each new solver trace the program again."""
+
+    fn: Callable
+    static: tuple = ()
+
+    def __call__(self, *args):
+        return self.fn(*self.static, *args)
+
+
 def _deployment_ops(mesh) -> SimpleNamespace:
     """:data:`_OPS` of the deployment ``mesh`` selects, with one call
     signature: the sharded functions get ``mesh`` bound."""
@@ -84,8 +99,12 @@ def _deployment_ops(mesh) -> SimpleNamespace:
             for n in _OPS})
     from repro.feti import sharded as shlib
 
-    return SimpleNamespace(**{n: partial(getattr(shlib, n), mesh)
+    return SimpleNamespace(**{n: _Call(getattr(shlib, n), (mesh,))
                               for n in _OPS})
+
+
+def _with_arrays(fn: Callable, static: tuple, arrays: tuple, x):
+    return fn(*arrays, *static, x)
 
 
 def _bound(fn: Callable, arrays: tuple, *static) -> Partial:
@@ -93,13 +112,75 @@ def _bound(fn: Callable, arrays: tuple, *static) -> Partial:
     leaves are ``arrays``: a jitted function that takes it as an argument
     receives the cluster state's arrays as program arguments, where a
     jitted closure over them would bake them into the program as
-    constants (gigabytes at full size)."""
-    return Partial(lambda arrs, x: fn(*arrs, *static, x), tuple(arrays))
+    constants (gigabytes at full size). ``fn`` and ``static`` are its
+    static data, compared by value (:class:`_Call`)."""
+    return Partial(_Call(_with_arrays, (fn, static)), tuple(arrays))
 
 
 def _at_dtype(dt, out_dt, op: Partial, x):
     """``op`` applied at the storage dtype ``dt`` to a solve-dtype vector."""
     return op(x.astype(dt)).astype(out_dt)
+
+
+def _rhs(dual_rhs: Callable, n_lambda: int, L, Btp, ids, c, fp):
+    return dual_rhs(L, Btp, fp, ids, n_lambda, c)
+
+
+def _rhs_refined(dual_rhs_refined: Callable, n_lambda: int, refine: int,
+                 L, Kreg, Btp, ids, c, fp):
+    return dual_rhs_refined(L, Kreg, Btp, fp, ids, n_lambda, refine, c)
+
+
+def _factor_solve_refined(solve: Callable, refine: int, L, Kreg, b):
+    return solve(L, Kreg, b, refine)
+
+
+def _solver_programs() -> SimpleNamespace:
+    """The solver's programs of one structure, defined afresh for each
+    (:meth:`repro.feti.programs.StructurePrograms.solver`), so that a
+    forgotten structure's compiled code goes with its functions. They take
+    the cluster state's arrays as arguments and their operators as static
+    data compared by value, so every solver of the structure and shapes
+    runs the programs the first one traced and loaded."""
+    # op(*xs) as one compiled program per operator: op by op, the
+    # factor-backed operators' unrolled block loops would dispatch (and on
+    # an accelerator, compile) hundreds of small operations per call
+    compiled = jax.jit(lambda op, *xs: op(*xs))
+
+    @jax.jit
+    def dual_apply(op, x):
+        """The solver's own dual-operator applications (refinement
+        residuals, recovery), under the scope PCPG gives its own."""
+        with jax.named_scope(DUAL_APPLY_SCOPE):
+            return op(x)
+
+    @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+    def pcpg_run(loop, tol, max_iter, history, mesh, apply_F, coarse,
+                 precond, d, lam0, atol):
+        """Single-RHS PCPG: ``loop`` is :func:`repro.feti.pcpg.pcpg`,
+        static like the operators' functions, so the program is keyed by
+        every function it runs.
+
+        The program is named: the device trace's scope paths and the
+        ``jit:*`` spans then say which program ran, and the name is part
+        of the persistent compilation cache's key, which leaves out debug
+        info and with it the named scopes — a program of the same
+        operations under other scopes is another entry."""
+        return loop(apply_F, coarse.project, d, lam0, precondition=precond,
+                    tol=tol, max_iter=max_iter, mesh=mesh, history=history,
+                    atol=atol)
+
+    @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+    def pcpg_many_run(loop, tol, max_iter, history, mesh, apply_F, coarse,
+                      precond, D, Lam0, atol):
+        """Block PCPG (``loop`` is :func:`repro.feti.pcpg.pcpg_many`),
+        named and keyed like ``pcpg_run``."""
+        return loop(apply_F, coarse.project, D, Lam0, precondition=precond,
+                    tol=tol, max_iter=max_iter, mesh=mesh, history=history,
+                    atol=atol)
+
+    return SimpleNamespace(compiled=compiled, dual_apply=dual_apply,
+                           pcpg_run=pcpg_run, pcpg_many_run=pcpg_many_run)
 
 
 @dataclasses.dataclass
@@ -165,6 +246,7 @@ class _SolutionOps:
     coarse_e_cols: Callable  # F (S, n, r) -> E (S·k, r)
     factor_solve: Callable  # b (S, n) -> K_reg⁻¹ b, refined when refining
     factor_solve_many: Callable  # B (S, n, r) -> K_reg⁻¹ B
+    programs: SimpleNamespace  # the structure's (_solver_programs)
 
 
 class FetiSolver:
@@ -216,8 +298,6 @@ class FetiSolver:
         self.telemetry = Telemetry()
         self.timings: dict = {}  # deprecated flat view; prefer report()
         self._ops: Optional[_SolutionOps] = None
-        self._runs: dict = {}  # (tol, max_iter, history) -> jitted pcpg
-        self._many_runs: dict = {}  # (tol, max_iter, history) -> pcpg_many
 
     # ---- preprocessing (paper §2.2) ----
     def preprocess(self) -> ClusterState:
@@ -236,8 +316,6 @@ class FetiSolver:
         self.cfg = self.state.cfg  # resolved when "auto" was passed
         self.plan = self.state.plan
         self._ops = None  # operators close over state arrays
-        self._runs = {}
-        self._many_runs = {}
         self.timings["preprocess_s"] = time.perf_counter() - t0
         return self.state
 
@@ -246,7 +324,7 @@ class FetiSolver:
         """Coarse problem + operator closures, built once per state (in
         the span ``solution_ops``, its coarse problem synced) and cached:
         the pieces of the solution phase that do NOT depend on the load,
-        so streamed load cases reuse them (and their jit caches)."""
+        so streamed load cases reuse them."""
         if self._ops is None:
             with self.telemetry.tracer.span("solution_ops") as sp:
                 self._ops = self._build_solution_ops()
@@ -260,37 +338,25 @@ class FetiSolver:
         sdt = self.solve_dtype  # f64 when refining, else the storage dtype
         refine = st.refine_steps
         c = jnp.asarray(prob.c, dtype=sdt)
-        Bt_host = np.stack([sd.Bt for sd in prob.subdomains])
+        BR = projector.interface_kernels(prob.subdomains)  # (S, m_max, k)
         k = _deployment_ops(st.mesh)
-        # op(*xs) as one compiled program per operator: op by op, the
-        # factor-backed operators' unrolled block loops would dispatch (and
-        # on an accelerator, compile) hundreds of small operations per call
-        compiled = jax.jit(lambda op, *xs: op(*xs))
-
-        # the solver's own dual-operator applications (refinement
-        # residuals, recovery), under the scope PCPG gives its own
-        def dual_apply(op, x):
-            with jax.named_scope(DUAL_APPLY_SCOPE):
-                return op(x)
-
-        dual = jax.jit(dual_apply)
+        progs = st.programs.solver(_solver_programs)
 
         if st.mesh is None:
             coarse = projector.build_coarse_problem(
-                jnp.asarray(Bt_host, dtype=sdt), st.f, st.R, st.lambda_ids,
-                nl)
+                jnp.asarray(BR, dtype=sdt), st.f, st.R, st.lambda_ids, nl)
         else:
             from repro.feti import sharded as shlib
 
             # match the state's relabeled multiplier columns, pad the
             # dummy subdomains (zero gluing), and shard like the stacks
-            Bt_rel = shlib.relabel_columns(Bt_host, np.asarray(st.col_perm))
-            Bt_orig = shlib.shard_stack(
-                st.mesh, np.asarray(shlib.pad_stack(Bt_rel, st.S),
-                                    dtype=sdt))
+            BR_rel = shlib.relabel_columns(
+                BR.swapaxes(1, 2), np.asarray(st.col_perm)).swapaxes(1, 2)
             coarse = shlib.build_coarse_problem(
-                st.mesh, Bt_orig, st.f, st.R, st.lambda_ids, nl,
-                S_real=st.S_real,
+                st.mesh, shlib.shard_stack(
+                    st.mesh, np.asarray(shlib.pad_stack(BR_rel, st.S),
+                                        dtype=sdt)),
+                st.f, st.R, st.lambda_ids, nl, S_real=st.S_real,
             )
 
         if refine > 0:
@@ -317,8 +383,8 @@ class FetiSolver:
         if refine > 0:
             # compiled: the defect-correction outer loop calls these
             # eagerly several times per solve
-            apply_F_exact = partial(dual, exact)
-            apply_F_exact_many = partial(dual, exact_many)
+            apply_F_exact = partial(progs.dual_apply, exact)
+            apply_F_exact_many = partial(progs.dual_apply, exact_many)
         else:
             # applied op by op: a named scope does not reach the programs
             # an eager call dispatches
@@ -355,7 +421,7 @@ class FetiSolver:
             # silently upcast the whole stack to f64 compute instead.
             # (apply_F_exact and the refined implicit apply stay unwrapped:
             # they are f64-accurate by construction.)
-            fast = partial(_at_dtype, stor_dt, sdt)
+            fast = _Call(_at_dtype, (stor_dt, sdt))
             if self.mode == "explicit":
                 apply_F = Partial(fast, apply_F)
                 apply_F_many = Partial(fast, apply_F_many)
@@ -365,25 +431,23 @@ class FetiSolver:
 
         if refine > 0:
             dual_rhs_vec = Partial(
-                lambda L, Kreg, Btp, ids, c, fp: k.dual_rhs_refined(
-                    L, Kreg, Btp, fp, ids, nl, refine, c), *refined, c)
+                _Call(_rhs_refined, (k.dual_rhs_refined, nl, refine)),
+                *refined, c)
             dual_rhs_cols = Partial(
-                lambda L, Kreg, Btp, ids, c, Fp: k.dual_rhs_refined_many(
-                    L, Kreg, Btp, Fp, ids, nl, refine, c), *refined, c)
+                _Call(_rhs_refined, (k.dual_rhs_refined_many, nl, refine)),
+                *refined, c)
             factor_solve = Partial(
-                lambda L, Kreg, b: solve_with_factor_refined(
-                    L, Kreg, b, refine), st.L, st.Kreg)
+                _Call(_factor_solve_refined,
+                      (solve_with_factor_refined, refine)), st.L, st.Kreg)
             factor_solve_many = Partial(
-                lambda L, Kreg, B: solve_with_factor_refined_many(
-                    L, Kreg, B, refine), st.L, st.Kreg)
+                _Call(_factor_solve_refined,
+                      (solve_with_factor_refined_many, refine)),
+                st.L, st.Kreg)
         else:
-            dual_rhs_vec = Partial(
-                lambda L, Btp, ids, c, fp: k.dual_rhs(L, Btp, fp, ids, nl,
-                                                      c),
-                st.L, st.Btp, st.lambda_ids, c)
-            dual_rhs_cols = Partial(
-                lambda L, Btp, ids, c, Fp: k.dual_rhs_many(
-                    L, Btp, Fp, ids, nl, c), st.L, st.Btp, st.lambda_ids, c)
+            dual_rhs_vec = Partial(_Call(_rhs, (k.dual_rhs, nl)),
+                                   st.L, st.Btp, st.lambda_ids, c)
+            dual_rhs_cols = Partial(_Call(_rhs, (k.dual_rhs_many, nl)),
+                                    st.L, st.Btp, st.lambda_ids, c)
             factor_solve = Partial(solve_with_factor, st.L)
             factor_solve_many = Partial(solve_with_factor_many, st.L)
 
@@ -392,12 +456,13 @@ class FetiSolver:
             apply_F_exact=apply_F_exact,
             apply_F_exact_many=apply_F_exact_many,
             precond=precond, precond_many=precond_many,
-            dual_rhs_vec=partial(compiled, dual_rhs_vec),
-            dual_rhs_cols=partial(compiled, dual_rhs_cols),
+            dual_rhs_vec=partial(progs.compiled, dual_rhs_vec),
+            dual_rhs_cols=partial(progs.compiled, dual_rhs_cols),
             coarse_e_vec=lambda f: k.coarse_e(f, st.R),
             coarse_e_cols=lambda F: k.coarse_e_many(F, st.R),
-            factor_solve=partial(compiled, factor_solve),
-            factor_solve_many=partial(compiled, factor_solve_many),
+            factor_solve=partial(progs.compiled, factor_solve),
+            factor_solve_many=partial(progs.compiled, factor_solve_many),
+            programs=progs,
         )
 
     def _load_stacks(self, loads: np.ndarray):
@@ -469,6 +534,7 @@ class FetiSolver:
 
         with use_tracer(tr), tr.span("solve", mode=self.mode) as sp_solve:
             ops = self._solution_ops()
+            st.programs.trim()  # a sweep's compiled variants stay bounded
             coarse = ops.coarse
             t0 = time.perf_counter()
             with tr.span("rhs_setup"):
@@ -585,58 +651,26 @@ class FetiSolver:
         )
 
     def _run(self, tol: float, max_iter: int, history: bool = False):
-        """Jitted single-RHS PCPG runner, cached per (tol, max_iter,
-        history): a stream of single load cases (``solve(loads=...)`` or
-        1-column :meth:`solve_many` batches) traces and compiles exactly
-        once per tolerance instead of once per call. The cached wrapper
-        runs the same compiled program a fresh ``jax.jit`` would, so
-        results are bit-identical to the uncached form. Called as
-        ``run(d, lam0, atol)``; ``atol`` floors the stopping threshold
-        (:func:`repro.feti.pcpg.pcpg`).
-
-        The program is named (``pcpg_run``): the device trace's scope
-        paths and the ``jit:*`` spans then say which program ran, and the
-        name is part of the persistent compilation cache's key, which
-        leaves out debug info and with it the named scopes — a program of
-        the same operations under other scopes is another entry."""
-        key = (float(tol), int(max_iter), bool(history))
-        run = self._runs.get(key)
-        if run is None:
-            ops = self._solution_ops()
-
-            def pcpg_run(apply_F, coarse, precond, d_, lam0_, atol_):
-                return pcpg(
-                    apply_F, coarse.project, d_, lam0_,
-                    precondition=precond, tol=tol, max_iter=max_iter,
-                    mesh=self.state.mesh, history=history, atol=atol_,
-                )
-
-            run = partial(jax.jit(pcpg_run), ops.apply_F, ops.coarse,
-                          ops.precond)
-            self._runs[key] = run
-        return run
+        """The single-RHS PCPG program (the structure's ``pcpg_run``,
+        :func:`_solver_programs`) with this solver's operators and static
+        arguments bound, called as ``run(d, lam0, atol)``; ``atol`` floors
+        the stopping threshold (:func:`repro.feti.pcpg.pcpg`). A stream of
+        single load cases (``solve(loads=...)`` or 1-column
+        :meth:`solve_many` batches) traces and compiles once per tolerance
+        and shapes, for every solver of the structure."""
+        ops = self._solution_ops()
+        return partial(ops.programs.pcpg_run, pcpg, float(tol),
+                       int(max_iter), bool(history), self.state.mesh,
+                       ops.apply_F, ops.coarse, ops.precond)
 
     def _many_run(self, tol: float, max_iter: int, history: bool = False):
-        """Jitted block-PCPG runner, cached per (tol, max_iter, history)
-        so a stream of equally-shaped batches compiles exactly once
-        (jax.jit handles distinct (n_lambda, n_rhs) shapes within one
-        runner)."""
-        key = (float(tol), int(max_iter), bool(history))
-        run = self._many_runs.get(key)
-        if run is None:
-            ops = self._solution_ops()
-
-            def pcpg_many_run(apply_F, coarse, precond, D_, Lam0_, atol_):
-                return pcpg_many(
-                    apply_F, coarse.project, D_, Lam0_,
-                    precondition=precond, tol=tol, max_iter=max_iter,
-                    mesh=self.state.mesh, history=history, atol=atol_,
-                )
-
-            run = partial(jax.jit(pcpg_many_run), ops.apply_F_many,
-                          ops.coarse, ops.precond_many)
-            self._many_runs[key] = run
-        return run
+        """The block-PCPG program (the structure's ``pcpg_many_run``),
+        bound like :meth:`_run` (jax.jit handles distinct (n_lambda,
+        n_rhs) shapes within one program)."""
+        ops = self._solution_ops()
+        return partial(ops.programs.pcpg_many_run, pcpg_many, float(tol),
+                       int(max_iter), bool(history), self.state.mesh,
+                       ops.apply_F_many, ops.coarse, ops.precond_many)
 
     def solve_many(self, loads, tol: float = 1e-9, max_iter: int = 2000,
                    rhs_unit: int = 1,
@@ -708,6 +742,7 @@ class FetiSolver:
         with use_tracer(tr), tr.span("solve", mode=self.mode,
                                      n_rhs=int(n_rhs)) as sp_solve:
             ops = self._solution_ops()
+            st.programs.trim()
             coarse = ops.coarse
             t0 = time.perf_counter()
             with tr.span("rhs_setup"):

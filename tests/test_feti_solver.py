@@ -11,6 +11,7 @@ from repro.fem import decompose_problem
 from repro.feti import FetiConfig, FetiSolver
 from repro.feti.assembly import preprocess_cluster
 from repro.feti.operator import explicit_dual_apply, implicit_dual_apply
+from repro.feti.projector import interface_kernels
 
 
 @pytest.fixture(scope="module", params=["heat", "elasticity"])
@@ -56,6 +57,21 @@ def test_feti_3d_matches_global_solve(prob3d, mode):
     sol = solver.solve(tol=1e-10)
     assert sol.converged
     _check_against_reference(prob3d, sol)
+
+
+def test_interface_kernels_match_the_dense_contraction(prob2d):
+    """B̃ᵢRᵢ from the compact gluing record is the dense B̃ᵢᵀ contraction
+    bit for bit, for k = 1 (heat) and k = 3 (elasticity), padding columns
+    (zero) included."""
+    subs = prob2d.subdomains
+    Bt = np.stack([sd.Bt for sd in subs])
+    R = np.stack([sd.R for sd in subs])
+    assert any((sd.b_vals == 0).any() for sd in subs)  # padding present
+    BR = interface_kernels(subs)
+    assert BR.shape == (len(subs), prob2d.m_max, R.shape[-1])
+    np.testing.assert_array_equal(BR, np.einsum("snm,snk->smk", Bt, R))
+    for sd, br in zip(subs, BR):
+        assert not br[sd.b_vals == 0].any()
 
 
 def test_explicit_equals_implicit_operator(prob2d):

@@ -25,6 +25,7 @@ from repro.feti.pcpg import (
     _clamp_tol,
     reset_tol_clamp_warnings,
 )
+from repro.feti.programs import forget
 from repro.obs import Telemetry, Tracer, metrics
 from repro.obs.trace import (
     _NULL_SPAN,
@@ -53,8 +54,10 @@ def _solver(prob):
 
 @pytest.fixture(scope="module")
 def first_solve(prob):
-    """A fresh solver after its first solve, with that solve's wall time
-    (preprocessing included)."""
+    """The first solver of its structure in the process (the programs
+    built so far forgotten) after its first solve, with that solve's wall
+    time (preprocessing included)."""
+    forget()
     solver = _solver(prob)
     t0 = time.perf_counter()
     solver.solve(tol=1e-8, max_iter=200)
@@ -179,6 +182,13 @@ def test_first_solve_records_program_builds_and_the_second_none(
         "stage:dual", "pcpg"}
     assert {sp.attrs["fun"] for sp in builds} >= {"prep", "pcpg_run"}
     assert sum(sp.duration for sp in builds) <= wall
+    # a second solver of the structure builds nothing: it runs the
+    # programs the first one built
+    second = _solver(solver.problem)
+    second.solve(tol=1e-8, max_iter=200)
+    assert _builds(second.telemetry.tracer.spans) == []
+    assert second.telemetry.tracer.last("init").attrs == {
+        "prep_program": "hit"}
     # the steady state rebuilds no program
     n = len(tr.spans)
     solver.solve(tol=1e-8, max_iter=200)
@@ -245,11 +255,18 @@ def test_pcpg_runner_names_its_operator_scopes(first_solve, prob,
     scoped_hlo = _strip_metadata(lowered.compile().as_text())
     monkeypatch.setattr(sys.modules["repro.feti.pcpg"], "scoped",
                         lambda name, fn: fn)
-    plain = _solver(prob)
-    plain.preprocess()
-    plain_lowered = _runner_lowered(plain)
-    assert "feti:project" not in plain_lowered.as_text(debug_info=True)
-    assert _strip_metadata(plain_lowered.compile().as_text()) == scoped_hlo
+    # the structure's programs are kept: trace them again unscoped, and
+    # forget the unscoped ones after
+    forget()
+    try:
+        plain = _solver(prob)
+        plain.preprocess()
+        plain_lowered = _runner_lowered(plain)
+        assert "feti:project" not in plain_lowered.as_text(debug_info=True)
+        assert (_strip_metadata(plain_lowered.compile().as_text())
+                == scoped_hlo)
+    finally:
+        forget()
 
 
 # ------------------------------------------------ exports + schema ----

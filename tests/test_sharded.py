@@ -59,14 +59,19 @@ def sharded_state(prob, mesh):
     return preprocess_cluster(prob, FetiConfig(schur=CFG, mesh=mesh))
 
 
-def _bt_stack(prob):
-    return np.stack([sd.Bt for sd in prob.subdomains])
+def _br_stack(prob):
+    from repro.feti.projector import interface_kernels
+
+    return interface_kernels(prob.subdomains)
 
 
-def _relabeled_padded_bt(prob, st1, st_sh, mesh):
-    """Original-row-order B̃ᵀ in the sharded layout (relabeled + padded)."""
-    Bt_rel = shlib.relabel_columns(_bt_stack(prob), np.asarray(st1.col_perm))
-    return shlib.shard_stack(mesh, shlib.pad_stack(Bt_rel, st_sh.S))
+def _relabeled_padded_br(prob, st1, st_sh, mesh):
+    """The (S, m_max, k) B̃R stack in the sharded layout (multiplier
+    columns relabeled, dummy subdomains padded)."""
+    BR_rel = shlib.relabel_columns(
+        _br_stack(prob).swapaxes(1, 2), np.asarray(st1.col_perm)
+    ).swapaxes(1, 2)
+    return shlib.shard_stack(mesh, shlib.pad_stack(BR_rel, st_sh.S))
 
 
 # --------------------------------------------------------------------------
@@ -314,7 +319,7 @@ def test_sharded_coarse_problem_matches(prob, mesh, single, sharded_state):
     st1, st_sh = single, sharded_state
     nl = prob.n_lambda
     c1 = build_single(
-        jnp.asarray(_bt_stack(prob)),
+        jnp.asarray(_br_stack(prob)),
         st1.f,
         st1.R,
         st1.lambda_ids,
@@ -322,7 +327,7 @@ def test_sharded_coarse_problem_matches(prob, mesh, single, sharded_state):
     )
     c_sh = shlib.build_coarse_problem(
         mesh,
-        _relabeled_padded_bt(prob, st1, st_sh, mesh),
+        _relabeled_padded_br(prob, st1, st_sh, mesh),
         st_sh.f,
         st_sh.R,
         st_sh.lambda_ids,
